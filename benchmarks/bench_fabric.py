@@ -23,6 +23,7 @@ import json
 import os
 import time
 
+import jax
 import numpy as np
 
 from repro.core.bdt import GradientBoostedClassifier
@@ -32,6 +33,7 @@ from repro.core.readout import ReadoutChip
 from repro.core.synth import synth_ensemble
 from repro.data.smartpixel import SmartPixelConfig, generate, train_test_split
 from repro.kernels.bdt_infer import ops as bdt_ops
+from repro.kernels.compat import default_interpret
 from repro.kernels.lut_eval import ops as lut_ops
 
 _SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "") == "1"
@@ -452,10 +454,8 @@ def run(emit):
     REPRO_BENCH_PROFILE=DIR) is set, the whole suite runs under a
     ``jax.profiler`` trace written to DIR — open it with
     ``tensorboard --logdir DIR`` or xprof to see the per-dispatch
-    timeline (word-domain eval, sparse compaction, donation reuse)."""
+    timeline (word-domain eval, sparse compaction)."""
     if _PROFILE_DIR:
-        import jax
-
         jax.profiler.start_trace(_PROFILE_DIR)
     try:
         _run(emit)
@@ -508,9 +508,12 @@ def _run(emit):
          f"packs_per_s={1 / t_pack:.0f};banded={str(packed.banded).lower()};"
          f"band_k={packed.band_k};levels={packed.n_levels}")
 
+    # the backend whose Pallas interpreter ran the kernel, or "off" where
+    # Mosaic compiled it (TPU)
+    interpret_mode = jax.default_backend() if default_interpret() else "off"
     t_mm, out = _time(lambda: np.asarray(lut_ops.fabric_eval(packed, bits)))
     note(f"fabric.bdt_lut_eval_matmul_{n_ev}ev", t_mm * 1e6,
-         f"events_per_s={n_ev / t_mm:.0f};interpret_mode=cpu;"
+         f"events_per_s={n_ev / t_mm:.0f};interpret_mode={interpret_mode};"
          f"banded={str(packed.banded).lower()}")
 
     # --- bit-sliced evaluation: 32 events per uint32 lane, each LUT a

@@ -17,6 +17,7 @@ from benchmarks import (
     bench_bdt, bench_fabric, bench_latency, bench_net, bench_power,
     bench_resources, layout_matrix, roofline,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "bdt": bench_bdt,              # Table 1 + §5 float numbers
@@ -41,6 +42,7 @@ def main() -> None:
             continue
         names.append(arg)
     names = names or list(MODULES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
 
     def emit(name: str, us: float, derived: str = ""):

@@ -52,6 +52,7 @@ import os
 import sys
 import time
 
+import jax
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -60,6 +61,8 @@ from repro.core.bdt import GradientBoostedClassifier
 from repro.core.readout import ReadoutChip
 from repro.data.pipeline import FrameStream, FrameStreamConfig
 from repro.data.smartpixel import SmartPixelConfig, generate, train_test_split
+from repro.kernels.compat import default_interpret
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.readout_server import ReadoutServer, ServerConfig
 
 
@@ -147,6 +150,13 @@ def main():
                  "(there is no budget to act on)")
     overload_policy = args.overload_policy or "observe"
 
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    device = f"{dev.platform} {dev.device_kind!r} x{jax.device_count()}"
+    # say where the kernels run: on anything but a TPU they are the Pallas
+    # interpreter, and every rate below is that device's, not the chip's
+    print(f"JAX device: {device}"
+          + (" (Pallas interpret mode)" if default_interpret() else ""))
     print(f"training {args.chips} chips ...")
     chips = [
         train_chip(seed=2024 + i, depth=5 - (i % 2), leaves=10 - (i % 3))
@@ -222,7 +232,8 @@ def main():
     r = server.report()
     dt = time.monotonic() - t0
     print(f"\ndone in {dt:.1f}s — {r['n_in']:,} events through "
-          f"{r['n_chips']} chips ({r['n_in']/dt:,.0f} ev/s incl. host sim)")
+          f"{r['n_chips']} chips ({r['n_in']/dt:,.0f} ev/s on {device}, "
+          "incl. compile and host sim)")
     print("per-stage timing (host-visible seconds / calls):")
     for stage, t in r["stages"].items():
         print(f"  {stage:18s} {t['seconds']:8.3f}s  x{t['calls']}")

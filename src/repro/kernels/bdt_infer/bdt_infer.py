@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(
     x_ref, featsel_ref, thr_ref, root_ref, left_ref, right_ref,
@@ -101,7 +99,7 @@ def bdt_infer_pallas(
         out_specs=pl.BlockSpec((batch_tile, 128), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 128), jnp.int32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
     )(x_raw, featsel, thr, root_onehot, left, right, value_hi, value_lo)

@@ -1,48 +1,14 @@
-"""JAX-version compatibility + shared defaults for the Pallas kernels.
+"""Shared interpret-mode default for the Pallas kernels.
 
-This container family spans JAX releases; everything release-dependent the
-kernels need lives here, once, instead of a per-file getattr:
-
-  * ``CompilerParams``: the TPU compiler-params class was renamed
-    (TPUCompilerParams -> CompilerParams).
-  * ``default_interpret()``: the shared interpret-mode default — every
-    kernel wrapper runs interpret everywhere except a real TPU backend.
-    One helper (not three per-kernel copies) so a future backend gains
-    compiled support in exactly one place.
-  * ``shard_map_compat()``: newer JAX exposes ``jax.shard_map`` with
-    partial-manual ``axis_names``; on older releases only
-    ``jax.experimental.shard_map.shard_map`` exists, and its
-    partial-manual form (``auto=...``) trips an XLA partitioner check, so
-    we fall back to a fully-manual region there (axes not named in
-    ``manual_axes`` are simply replicated through the body). Used by the
-    fused readout frontend (kernels/frontend.py) to shard the chip axis
-    and by the compressed gradient all-reduce (parallel/compression.py).
+``default_interpret()`` is the one place that decides whether a kernel
+wrapper interprets: everywhere except a real TPU backend, where Mosaic
+compiles the kernels.
 """
 from __future__ import annotations
 
 import jax
-import jax.experimental.pallas.tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-_HAS_PARTIAL_MANUAL = hasattr(jax, "shard_map")
 
 
 def default_interpret() -> bool:
     """Pallas kernels interpret everywhere but TPU (Mosaic)."""
     return jax.default_backend() != "tpu"
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes):
-    """``jax.shard_map`` across JAX versions (see module docstring)."""
-    if _HAS_PARTIAL_MANUAL:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=frozenset(manual_axes), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )

@@ -50,7 +50,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.fabric import FabricConfig, FrontendSpec
 from repro.core.quantize import (
@@ -59,7 +59,7 @@ from repro.core.quantize import (
     spec_device_params,
 )
 from repro.data.smartpixel import N_T, N_X, N_Y
-from repro.kernels.compat import default_interpret, shard_map_compat
+from repro.kernels.compat import default_interpret
 from repro.kernels.lut_eval import bitsliced as _bitsliced
 from repro.kernels.lut_eval import ops as lut_ops
 from repro.kernels.yprofile import ops as yp_ops
@@ -215,11 +215,11 @@ def _score_frames_impl(
                 voted_w, dis_w, plan["out_weight"], plan["threshold_raw"],
                 valid)
 
-        keep_w, scores, dis = shard_map_compat(
+        keep_w, scores, dis = jax.shard_map(
             body_sparse, mesh=mesh,
             in_specs=(shard,) * 8,
             out_specs=(shard, shard, shard),
-            manual_axes={"chips"},
+            check_vma=False,
         )(frames, y0, sel, tables, output_nets, plan, valid, src)
         # Cross-chip compaction: one ascending flat index space, so it runs
         # after the manual region but inside the same jit.
@@ -245,31 +245,19 @@ def _score_frames_impl(
             outs, disagree, plan["out_weight"], plan["threshold_raw"],
             valid)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard,) * 8,
         out_specs=(shard, shard, shard),
-        manual_axes={"chips"},
+        check_vma=False,
     )(frames, y0, sel, tables, output_nets, plan, valid, src)
 
 
-_SCORE_STATICS = ("mesh", "n_replicas", "threshold_electrons", "n_inputs",
-                  "in_seg", "n_nets_pad", "batch_tile", "interpret", "sparse")
-
 _score_frames = functools.partial(
-    jax.jit, static_argnames=_SCORE_STATICS,
-)(_score_frames_impl)
-
-# The zero-copy serving twin: frames and y0 — by far the largest inflight
-# buffers, (C, B, T, Y, X) f32 — are DONATED, so XLA reuses their device
-# memory for intermediates instead of holding both live across the
-# dispatch. The caller must treat the exact arrays it passed as dead
-# (the readout server stages fresh buffers per dispatch, so serving is
-# always donation-safe). Donation is a no-op with a warning on backends
-# that don't implement it (CPU), hence the separate twin — pack_frontend
-# selects it per backend.
-_score_frames_donated = functools.partial(
-    jax.jit, static_argnames=_SCORE_STATICS, donate_argnums=(0, 1),
+    jax.jit,
+    static_argnames=("mesh", "n_replicas", "threshold_electrons", "n_inputs",
+                     "in_seg", "n_nets_pad", "batch_tile", "interpret",
+                     "sparse"),
 )(_score_frames_impl)
 
 
@@ -289,10 +277,6 @@ class FusedFrontend:
     batch_tile: int
     threshold_electrons: float
     interpret: bool
-    # Donate (frames, y0) to the dispatch: zero-copy, but the arrays a
-    # caller passed to score_frames* are DEAD afterwards — reuse is an
-    # error. False on backends without donation support (CPU).
-    donate: bool = False
 
     @property
     def n_chips(self) -> int:
@@ -328,8 +312,8 @@ class FusedFrontend:
         rows; None = all rows) where that replica's output word was voted
         against. All-zero on a healthy (or non-redundant) stack.
 
-        With ``donate=True`` the (frames, y0) device buffers are consumed
-        by the dispatch: do not reuse the exact arrays passed in."""
+        ``frames``/``y0``/``valid`` are host arrays; the dispatch places
+        its own copies on the mesh."""
         score, keep, dis = self._dispatch(frames, y0, valid, sparse=False)
         B = np.shape(frames)[1]
         return score[:, :B], keep[:, :B], dis
@@ -349,7 +333,7 @@ class FusedFrontend:
         ``parallel.compression.sparse_trigger_pack`` wire format. Results
         are NOT materialized; slice ``idx[:count]`` on device before
         np.asarray to ship exactly the kept events (the server's drain
-        does). Same donation invariant as ``score_frames_voted``."""
+        does)."""
         C, B = np.shape(frames)[0], np.shape(frames)[1]
         count, idx, vals, dis = self._dispatch(frames, y0, valid,
                                                sparse=True)
@@ -363,24 +347,26 @@ class FusedFrontend:
         return count, idx, vals, dis
 
     def _dispatch(self, frames, y0, valid, *, sparse: bool):
-        frames = jnp.asarray(frames, jnp.float32)
-        y0 = jnp.asarray(y0, jnp.float32)
+        frames = np.asarray(frames, np.float32)
+        y0 = np.asarray(y0, np.float32)
         C, B = frames.shape[0], frames.shape[1]
         assert C == self.n_chips, (C, self.n_chips)
-        if valid is None:
-            valid = jnp.ones((C, B), jnp.bool_)
-        else:
-            valid = jnp.asarray(valid, jnp.bool_)
+        valid = (np.ones((C, B), np.bool_) if valid is None
+                 else np.asarray(valid, np.bool_))
         Bp = (max(B, 1) + self.batch_tile - 1) // self.batch_tile
         Bp *= self.batch_tile
         if Bp != B:
             pad = ((0, 0), (0, Bp - B))
-            frames = jnp.pad(frames, pad + ((0, 0),) * 3)
-            y0 = jnp.pad(y0, pad)
-            valid = jnp.pad(valid, pad)
+            frames = np.pad(frames, pad + ((0, 0),) * 3)
+            y0 = np.pad(y0, pad)
+            valid = np.pad(valid, pad)
+        # Host buffers go straight to their chip-axis shards: one transfer
+        # per device, instead of a copy onto the default device that the
+        # sharded dispatch would then have to redistribute.
+        frames, y0, valid = jax.device_put(
+            (frames, y0, valid), NamedSharding(self.mesh, P("chips")))
         s = self.stack
-        fn = _score_frames_donated if self.donate else _score_frames
-        return fn(
+        return _score_frames(
             frames, y0, s.sel, s.tables, s.level_base, s.win_base,
             s.output_nets, self.plan, valid, s.src,
             mesh=self.mesh, n_replicas=s.n_replicas,
@@ -435,7 +421,6 @@ def pack_frontend(
     mesh: Optional[Mesh] = None,
     interpret: Optional[bool] = None,
     stack: Optional[lut_ops.PackedFabricStack] = None,
-    donate: Optional[bool] = None,
 ) -> FusedFrontend:
     """Pack N (config, frontend-spec) pairs into one fused dispatch.
 
@@ -453,13 +438,6 @@ def pack_frontend(
     replica encodings voted on device (see lut_eval.ops.pack_fabrics);
     the encode plan stays per logical chip — featurize/quantize/pack run
     once per chip, only the fabric stage is triplicated.
-
-    ``donate`` (None = auto: on wherever the backend implements buffer
-    donation, i.e. everywhere but CPU) makes the dispatch CONSUME the
-    (frames, y0) buffers — zero-copy inflight staging. Callers must not
-    reuse the exact arrays they passed to ``score_frames*`` afterwards;
-    the readout server stages fresh buffers per dispatch, so serving is
-    always donation-safe.
     """
     if len(configs) != len(chip_specs):
         raise ValueError(f"{len(configs)} configs vs {len(chip_specs)} specs")
@@ -489,6 +467,4 @@ def pack_frontend(
         batch_tile=batch_tile,
         threshold_electrons=float(threshold_electrons),
         interpret=default_interpret() if interpret is None else interpret,
-        donate=(jax.default_backend() != "cpu") if donate is None
-        else bool(donate),
     )

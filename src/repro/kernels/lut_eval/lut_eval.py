@@ -3,13 +3,14 @@
 The fabric's *spatial* parallelism (hundreds of LUT4s switching per clock)
 maps to TPU as *batch* parallelism over events (DESIGN.md §3). A LUT4 read
 is a 16-entry gather; random gathers are hostile to the TPU vector unit, so
-both stages are reformulated as dense one-hot contractions that run on the
-MXU:
+routing is reformulated as a dense one-hot contraction on the MXU and the
+lookup as a lane-dense shift on the VPU:
 
   stage 1 (routing):  ins = V @ S_l      — selecting each LUT's 4 input nets
-                      is a (B,N) x (N,4M) matmul with a 0/1 matrix;
-  stage 2 (lookup):   out = Σ_k 1[idx=k] * T_l[:,k] — a 16-way one-hot
-                      contraction against the truth tables.
+                      is a (B,N) x (N,4M) bf16 matmul with a 0/1 matrix;
+  stage 2 (lookup):   out = (T_l >> idx) & 1 — each LUT's 16 truth-table
+                      bits packed into one int32 word, shifted by the
+                      4-bit address idx.
 
 Memory layout: net values live in a VMEM-resident (B_TILE, N) f32 buffer.
 N is the *segmented* padded net count — [consts+inputs | level 0 | level 1
@@ -29,7 +30,9 @@ known to the DMA engine up front.
 
 VMEM budget per step (BDT module, N=2048, M=128, B=128):
   V 128x2048x4B = 1.0 MiB, S block 2048x512x2B (bf16) = 2.0 MiB,
-  tables 128x16x4B = 8 KiB  => ~3 MiB, comfortably under the ~16 MiB VMEM.
+  table words 128x4B => ~3 MiB, under Mosaic's default 16 MiB scoped limit.
+Deep ensembles on efpga_28nm_xl (M=256, N up to ~6.5k) need more; the
+scoped limit is sized from the blocks (``_compiler_params``).
 
 The selection matmul does ~B*N*4M flops per level — far more "arithmetic"
 than the fabric's actual logic, but it is dense MXU work at 197 TFLOP/s
@@ -58,7 +61,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+
+def _level_out(v, sel, tbl, m_pad: int):
+    """One level of every LUT: route, then look the 4-bit address up.
+
+    ``v`` (B, rows) holds 0/1 net values and ``sel`` is a 0/1 one-hot, so
+    the bf16 routing matmul is exact and never needs an f32 copy of the
+    selection block. ``tbl`` (1, M) int32 packs each LUT's 16 truth-table
+    bits, so the lookup is one lane-dense shift: a (B, M, 16) one-hot
+    would pad its 16-wide minor axis to 128 lanes in VMEM.
+    """
+    ins = jax.lax.dot(v.astype(jnp.bfloat16), sel,
+                      preferred_element_type=jnp.float32)  # (B, 4*M)
+    idx = (
+        ins[:, :m_pad]
+        + 2.0 * ins[:, m_pad : 2 * m_pad]
+        + 4.0 * ins[:, 2 * m_pad : 3 * m_pad]
+        + 8.0 * ins[:, 3 * m_pad :]
+    ).astype(jnp.int32)                                 # (B, M)
+    return (jnp.right_shift(tbl, idx) & 1).astype(jnp.float32)
+
+
+def _table_words(tables: jnp.ndarray) -> jnp.ndarray:
+    """(C, L, M, 16) 0/1 truth tables -> (C, L, 1, M) int32 bit masks
+    (bit k = table entry k), the kernels' lookup operand."""
+    bits = (tables > 0.5).astype(jnp.int32) << jnp.arange(16, dtype=jnp.int32)
+    return jnp.sum(bits, axis=-1, dtype=jnp.int32)[:, :, None, :]
+
+
+def _compiler_params(*, batch_tile, in_seg, n_nets_pad, sel_rows, m_pad):
+    """Grid semantics plus a scoped-VMEM budget sized to the blocks.
+
+    Mosaic's default scoped limit is 16 MiB; a deep ensemble's selection
+    block alone can exceed it. Budget the double-buffered blocks and as
+    much again for in-kernel temporaries, never below the default.
+    """
+    block_bytes = (4 * batch_tile * (in_seg + n_nets_pad)   # bits, net buffer
+                   + 2 * sel_rows * 4 * m_pad               # bf16 selection
+                   + 4 * 8 * m_pad)                         # table words
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=max(16 << 20, 4 * block_bytes),
+    )
 
 
 def _kernel(base_ref, bits_ref, sel_ref, tbl_ref, vals_ref, *, in_seg: int, m_pad: int):
@@ -70,17 +114,10 @@ def _kernel(base_ref, bits_ref, sel_ref, tbl_ref, vals_ref, *, in_seg: int, m_pa
         vals_ref[...] = jnp.zeros_like(vals_ref)
         vals_ref[0, :, : in_seg] = bits_ref[0]  # [const0, const1, inputs, pad]
 
-    v = vals_ref[0]                                     # (B, N)
-    sel = sel_ref[0, 0].astype(jnp.float32)             # (N, 4*M)
-    ins = jax.lax.dot(v, sel, preferred_element_type=jnp.float32)
-    ins = ins.reshape(v.shape[0], 4, m_pad)
-    idx = (
-        ins[:, 0] + 2.0 * ins[:, 1] + 4.0 * ins[:, 2] + 8.0 * ins[:, 3]
-    ).astype(jnp.int32)                                 # (B, M)
-    onehot = idx[..., None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, 16), 2)
-    out = jnp.sum(onehot.astype(jnp.float32) * tbl_ref[0, 0][None], axis=-1)
-
-    vals_ref[0, :, pl.dslice(base_ref[l], m_pad)] = out
+    out = _level_out(vals_ref[0], sel_ref[0, 0], tbl_ref[0, 0], m_pad)
+    # Mosaic must see that the lane offset is tile-aligned; the packer
+    # makes every level base a multiple of 128.
+    vals_ref[0, :, pl.dslice(pl.multiple_of(base_ref[l], 128), m_pad)] = out
 
 
 def lut_eval_pallas_stacked(
@@ -116,7 +153,7 @@ def lut_eval_pallas_stacked(
         in_specs=[
             pl.BlockSpec((1, batch_tile, in_seg), lambda c, b, l, base: (c, b, 0)),
             pl.BlockSpec((1, 1, N, M4), lambda c, b, l, base: (c, l, 0, 0)),
-            pl.BlockSpec((1, 1, M, 16), lambda c, b, l, base: (c, l, 0, 0)),
+            pl.BlockSpec((1, 1, 1, M), lambda c, b, l, base: (c, l, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, batch_tile, N), lambda c, b, l, base: (c, b, 0)),
     )
@@ -125,10 +162,10 @@ def lut_eval_pallas_stacked(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, B, N), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-    )(level_base, bits_ext.astype(jnp.float32), sel, tables)
+        compiler_params=_compiler_params(
+            batch_tile=batch_tile, in_seg=in_seg, n_nets_pad=N, sel_rows=N,
+            m_pad=M),
+    )(level_base, bits_ext.astype(jnp.float32), sel, _table_words(tables))
 
 
 def _banded_kernel(
@@ -150,18 +187,10 @@ def _banded_kernel(
         vals_ref[0, :, : in_seg] = bits_ref[0]  # [const0, const1, inputs, pad]
 
     v_in = vals_ref[0, :, :in_seg]                      # (B, in_seg)
-    v_win = vals_ref[0, :, pl.dslice(win_ref[l], band_m)]  # (B, K*M)
+    v_win = vals_ref[0, :, pl.dslice(pl.multiple_of(win_ref[l], 128), band_m)]
     v = jnp.concatenate([v_in, v_win], axis=-1)         # (B, in_seg + K*M)
-    sel = sel_ref[0, 0].astype(jnp.float32)             # (in_seg + K*M, 4*M)
-    ins = jax.lax.dot(v, sel, preferred_element_type=jnp.float32)
-    ins = ins.reshape(v.shape[0], 4, m_pad)
-    idx = (
-        ins[:, 0] + 2.0 * ins[:, 1] + 4.0 * ins[:, 2] + 8.0 * ins[:, 3]
-    ).astype(jnp.int32)                                 # (B, M)
-    onehot = idx[..., None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, 16), 2)
-    out = jnp.sum(onehot.astype(jnp.float32) * tbl_ref[0, 0][None], axis=-1)
-
-    vals_ref[0, :, pl.dslice(base_ref[l], m_pad)] = out
+    out = _level_out(v, sel_ref[0, 0], tbl_ref[0, 0], m_pad)
+    vals_ref[0, :, pl.dslice(pl.multiple_of(base_ref[l], 128), m_pad)] = out
 
 
 def lut_eval_pallas_banded_stacked(
@@ -206,7 +235,7 @@ def lut_eval_pallas_banded_stacked(
                 (1, 1, n_rows, M4), lambda c, b, l, base, win: (c, l, 0, 0)
             ),
             pl.BlockSpec(
-                (1, 1, M, 16), lambda c, b, l, base, win: (c, l, 0, 0)
+                (1, 1, 1, M), lambda c, b, l, base, win: (c, l, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -218,10 +247,11 @@ def lut_eval_pallas_banded_stacked(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, B, n_nets_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-    )(level_base, win_base, bits_ext.astype(jnp.float32), sel, tables)
+        compiler_params=_compiler_params(
+            batch_tile=batch_tile, in_seg=in_seg, n_nets_pad=n_nets_pad,
+            sel_rows=n_rows, m_pad=M),
+    )(level_base, win_base, bits_ext.astype(jnp.float32), sel,
+      _table_words(tables))
 
 
 def lut_eval_pallas(

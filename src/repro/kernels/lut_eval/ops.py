@@ -76,7 +76,6 @@ from repro.core.fabric import (
 )
 from repro.core.tmr import N_REPLICAS, majority_vote, replicate_config
 from repro.kernels.compat import default_interpret as _default_interpret
-from repro.kernels.compat import shard_map_compat as _shard_map_compat
 from repro.kernels.lut_eval import bitsliced as _bitsliced
 from repro.parallel.compression import sparse_trigger_pack_words
 from repro.kernels.lut_eval.lut_eval import (
@@ -1128,11 +1127,11 @@ def _eval_stack_scored(
             return decode_keep_words_device(
                 voted_w, dis_w, out_weight, threshold_raw, valid)
 
-        keep_w, scores, dis = _shard_map_compat(
+        keep_w, scores, dis = jax.shard_map(
             body_sparse, mesh=mesh,
             in_specs=(shard,) * 8,
             out_specs=(shard, shard, shard),
-            manual_axes={"chips"},
+            check_vma=False,
         )(sel, tables, output_nets, bits, out_weight, threshold_raw,
           valid, src)
         # Compaction is CROSS-chip (one ascending flat index space), so it
@@ -1152,11 +1151,11 @@ def _eval_stack_scored(
         return decode_scores_device(
             outs, disagree, out_weight, threshold_raw, valid)
 
-    return _shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard,) * 8,
         out_specs=(shard, shard, shard),
-        manual_axes={"chips"},
+        check_vma=False,
     )(sel, tables, output_nets, bits, out_weight, threshold_raw, valid, src)
 
 

@@ -24,13 +24,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+
+def _fold_dot(flat, fold):
+    """Charge (B, TYX_pad) x one-hot fold (TYX_pad, Y_pad), at f32.
+
+    Charges are fractional electrons in the thousands: a bf16 operand pass
+    would move a profile bin by many electrons, so the MXU is asked for
+    full f32 precision explicitly.
+    """
+    return jax.lax.dot(flat, fold, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
 
 
 def _kernel(frames_ref, fold_ref, y0_ref, out_ref, *, threshold: float):
     flat = frames_ref[...]                      # (B, TYX_pad)
     fold = fold_ref[...]                        # (TYX_pad, Y_pad)
-    prof = jax.lax.dot(flat, fold, preferred_element_type=jnp.float32)
+    prof = _fold_dot(flat, fold)
     prof = jnp.maximum(prof, 0.0)
     prof = jnp.where(prof > threshold, prof, 0.0) / 1000.0
     # slot y0 (um) into the first padding column after the Y bins
@@ -61,7 +70,7 @@ def yprofile_pallas(
         out_specs=pl.BlockSpec((batch_tile, 128), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 128), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(frames_flat, fold, y0_cols)
 
@@ -69,7 +78,7 @@ def yprofile_pallas(
 def _kernel_stacked(frames_ref, fold_ref, y0_ref, out_ref, *, threshold: float):
     flat = frames_ref[0]                        # (B, TYX_pad)
     fold = fold_ref[...]                        # (TYX_pad, Y_pad)
-    prof = jax.lax.dot(flat, fold, preferred_element_type=jnp.float32)
+    prof = _fold_dot(flat, fold)
     prof = jnp.maximum(prof, 0.0)
     prof = jnp.where(prof > threshold, prof, 0.0) / 1000.0
     out_ref[0] = prof + y0_ref[0]
@@ -105,6 +114,6 @@ def yprofile_pallas_stacked(
         out_specs=pl.BlockSpec((1, batch_tile, 128), lambda c, b: (c, b, 0)),
         out_shape=jax.ShapeDtypeStruct((C, B, 128), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(frames_flat, fold, y0_cols)

@@ -20,14 +20,10 @@ from jax.sharding import Mesh
 
 
 def make_mesh_compat(shape, axes) -> Mesh:
-    """jax.make_mesh across JAX versions: axis_types (and AxisType itself)
-    only exist in newer releases; all our meshes want Auto axes, which is
-    also the older versions' only behavior."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-sharded), the axis
+    type all of the repo's meshes use."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -102,8 +98,5 @@ def make_fleet_meshes(bucket_chip_counts: Sequence[int]) -> List[Mesh]:
     return meshes
 
 
-# TPU v5e hardware constants used by the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 50e9                 # B/s per link (~ per-direction)
+# HBM of one TPU v5e chip; the LM dry run checks its programs against it.
 HBM_BYTES = 16 * 1024**3      # 16 GiB

@@ -1252,8 +1252,6 @@ class ReadoutServer:
             trace["t_encoded"] = self._clock()
 
             t0 = self._clock()
-            # frames/y0 are freshly staged numpy buffers, dead after this
-            # call — exactly the donation contract of the fused dispatch.
             if self._word_sparse_active():
                 count, idx, vals, dis = (
                     self._get_frontend().score_frames_sparse(
@@ -2001,6 +1999,10 @@ class ReadoutServer:
             "n_replicas": self.n_replicas,
             "sparse": cfg.sparse,
             "n_chips": self.n_chips,
+            # ids of the devices the chip axis is sharded over (kernel
+            # backend; empty on the host oracle)
+            "devices": ([] if self._mesh is None else
+                        [int(d.id) for d in self._mesh.devices.flat]),
             "n_in": n_in,
             "n_kept": n_kept,
             "fraction_kept": n_kept / n_in if n_in else 1.0,

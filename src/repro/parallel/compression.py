@@ -47,11 +47,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 PyTree = Any
 
 
-# The JAX-version shard_map shim is shared with the fused readout frontend
-# (kernels/frontend.py); see kernels/compat.py for the fallback semantics.
-from repro.kernels.compat import shard_map_compat as _shard_map_compat  # noqa: E402
-
-
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """absmax-scaled symmetric int8. Returns (q, scale)."""
     xf = x.astype(jnp.float32)
@@ -133,10 +128,9 @@ def make_compressed_value_and_grad(
 
     def body(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        # intra-pod sharding constraints need the partial-manual form
-        # (data/model still auto); in the fully-manual fallback they would
-        # reference axes the region owns — skip them there (perf-only).
-        if inner_grad_specs is not None and _HAS_PARTIAL_MANUAL:
+        # intra-pod sharding constraints (data/model stay auto inside the
+        # partial-manual region)
+        if inner_grad_specs is not None:
             grads = jax.tree.map(
                 lambda g, s: jax.lax.with_sharding_constraint(g, s),
                 grads, inner_grad_specs)
@@ -145,12 +139,13 @@ def make_compressed_value_and_grad(
         loss = jax.lax.pmean(loss, "pod")
         return loss, grads
 
-    return _shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), in_batch_specs),
         out_specs=(P(), P()),
-        manual_axes={"pod"},
+        axis_names=frozenset({"pod"}),
+        check_vma=False,
     )
 
 

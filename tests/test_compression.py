@@ -90,7 +90,7 @@ def _word_form(score, keep):
     sp[:, :B] = score
     kp = np.zeros((C, W * 32), bool)
     kp[:, :B] = keep
-    keep_w = bitsliced.mask_words(jnp.asarray(kp))
+    keep_w = jax.jit(bitsliced.mask_words)(jnp.asarray(kp))
     return keep_w, jnp.asarray(sp.reshape(C, W, 32)), sp, kp
 
 
@@ -108,7 +108,7 @@ def test_sparse_word_pack_matches_event_oracle(seed, c, b, p_keep):
                          dtype=np.int64).astype(np.int32)
     keep = rng.random((c, b)) < p_keep
     keep_w, scores_w, sp, kp = _word_form(score, keep)
-    count0, idx0, vals0 = sparse_trigger_pack(
+    count0, idx0, vals0 = sparse_trigger_pack_jit(
         jnp.asarray(sp), jnp.asarray(kp))
     count1, idx1, vals1 = jax.jit(sparse_trigger_pack_words)(
         keep_w, scores_w)
