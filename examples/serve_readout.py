@@ -234,22 +234,21 @@ def main():
     print(f"\ndone in {dt:.1f}s — {r['n_in']:,} events through "
           f"{r['n_chips']} chips ({r['n_in']/dt:,.0f} ev/s on {device}, "
           "incl. compile and host sim)")
-    print("per-stage timing (host-visible seconds / calls):")
+    print("per-stage timing (host-visible seconds / calls / longest call):")
     for stage, t in r["stages"].items():
-        print(f"  {stage:18s} {t['seconds']:8.3f}s  x{t['calls']}")
+        print(f"  {stage:18s} {t['seconds']:8.3f}s  x{t['calls']:<6d} "
+              f"{t['max_s'] * 1e3:8.2f} ms")
     for pc in r["per_chip"]:
         seu = (f", SEU disagreements {pc['seu_disagreements']}"
                if r["redundancy"] == "tmr" else "")
         print(f"  chip {pc['chip']}: kept {pc['fraction_kept']:.1%} "
               f"(x{pc['data_reduction_factor']:.2f} reduction, "
-              f"link {pc['link_rate_in_gbps']:.0f} -> "
-              f"{pc['link_rate_out_gbps']:.1f} Gb/s, "
               f"{pc['n_dispatches']} dispatches{seu})")
     lb = r["link_bytes"]
-    if r["sparse"]:
-        print(f"host link: {lb['on_wire']:,} B on the sparse wire vs "
-              f"{lb['dense_equivalent']:,} B dense "
-              f"(x{lb['wire_reduction']:.2f} reduction)")
+    print(f"host link (measured): {lb['on_wire']:,} B on the "
+          f"{'sparse' if r['sparse'] else 'dense'} wire vs "
+          f"{lb['dense_equivalent']:,} B dense "
+          f"(x{lb['wire_reduction']:.2f} reduction)")
     if args.deadline_us is not None:
         dd = r["deadline"]
         lt = r["latency"]["total"]

@@ -148,6 +148,13 @@ _PLAN_KEYS = ("feat_idx", "bit_idx", "bit_valid", "out_weight",
               "sat_lo", "sat_hi")
 
 
+# Each stage of the fused step runs under a named scope, which its device
+# ops carry in their op metadata, so a profile can tell the stages apart:
+# readout_featurize (the yprofile kernel and the frames' relayout),
+# readout_encode (quantize and bit pack), readout_fabric_eval (bit-sliced
+# or matmul evaluation and the vote), readout_decode (score decode and
+# cut) and, on the sparse path, readout_compact.
+#
 # Static args are the ENVELOPE only (never per-chip values), so hot-swaps
 # and threshold updates are array swaps with no retrace — the same rule as
 # lut_eval's _eval_stack_arrays.
@@ -175,23 +182,27 @@ def _score_frames_impl(
 ):
     def encode(frames, y0, plan):
         # 1. featurize: chip-batched yprofile -> (Cl, B, 128) feature cols
-        feats = yp_ops.yprofile_traced(
-            frames, y0, threshold=threshold_electrons,
-            batch_tile=batch_tile, interpret=interpret)
-        # 2. quantize every feature column to its chip's offset-binary
-        #    pattern (per-chip spec params broadcast over (B, 128))
-        c1 = lambda a: a[:, None, None]
-        u = quantize_pattern_device(
-            feats, scale=c1(plan["scale"]), rnd_off=c1(plan["rnd_off"]),
-            wrap_mask=c1(plan["wrap_mask"]), sign_bit=c1(plan["sign_bit"]),
-            sat_lo=c1(plan["sat_lo"]), sat_hi=c1(plan["sat_hi"]))
-        # 3. pack input bits: bit j of chip c = bit bit_idx[c,j] of
-        #    feature feat_idx[c,j]'s pattern (the host packer's reshape,
-        #    as a gather that survives heterogeneous chips)
-        taken = jnp.take_along_axis(u, plan["feat_idx"][:, None, :], axis=2)
-        return jnp.bitwise_and(
-            jnp.right_shift(taken, plan["bit_idx"][:, None, :]), jnp.int32(1)
-        ) * plan["bit_valid"][:, None, :]
+        with jax.named_scope("readout_featurize"):
+            feats = yp_ops.yprofile_traced(
+                frames, y0, threshold=threshold_electrons,
+                batch_tile=batch_tile, interpret=interpret)
+        with jax.named_scope("readout_encode"):
+            # 2. quantize every feature column to its chip's offset-binary
+            #    pattern (per-chip spec params broadcast over (B, 128))
+            c1 = lambda a: a[:, None, None]
+            u = quantize_pattern_device(
+                feats, scale=c1(plan["scale"]), rnd_off=c1(plan["rnd_off"]),
+                wrap_mask=c1(plan["wrap_mask"]),
+                sign_bit=c1(plan["sign_bit"]),
+                sat_lo=c1(plan["sat_lo"]), sat_hi=c1(plan["sat_hi"]))
+            # 3. pack input bits: bit j of chip c = bit bit_idx[c,j] of
+            #    feature feat_idx[c,j]'s pattern (the host packer's
+            #    reshape, as a gather that survives heterogeneous chips)
+            taken = jnp.take_along_axis(u, plan["feat_idx"][:, None, :],
+                                        axis=2)
+            return jnp.bitwise_and(
+                jnp.right_shift(taken, plan["bit_idx"][:, None, :]),
+                jnp.int32(1)) * plan["bit_valid"][:, None, :]
 
     shard = P("chips")
 
@@ -208,12 +219,14 @@ def _score_frames_impl(
             # fused HERE, on device, against the just-encoded bit tensor —
             # packing never round-trips the host — and everything after it
             # stays in the word domain.
-            voted_w, dis_w = _bitsliced.eval_words_voted(
-                src, tables, output_nets, bits,
-                n_replicas=n_replicas, n_inputs=n_inputs, in_seg=in_seg)
-            return lut_ops.decode_keep_words_device(
-                voted_w, dis_w, plan["out_weight"], plan["threshold_raw"],
-                valid)
+            with jax.named_scope("readout_fabric_eval"):
+                voted_w, dis_w = _bitsliced.eval_words_voted(
+                    src, tables, output_nets, bits,
+                    n_replicas=n_replicas, n_inputs=n_inputs, in_seg=in_seg)
+            with jax.named_scope("readout_decode"):
+                return lut_ops.decode_keep_words_device(
+                    voted_w, dis_w, plan["out_weight"],
+                    plan["threshold_raw"], valid)
 
         keep_w, scores, dis = jax.shard_map(
             body_sparse, mesh=mesh,
@@ -223,7 +236,8 @@ def _score_frames_impl(
         )(frames, y0, sel, tables, output_nets, plan, valid, src)
         # Cross-chip compaction: one ascending flat index space, so it runs
         # after the manual region but inside the same jit.
-        count, idx, vals = sparse_trigger_pack_words(keep_w, scores)
+        with jax.named_scope("readout_compact"):
+            count, idx, vals = sparse_trigger_pack_words(keep_w, scores)
         return count, idx, vals, dis
 
     def body(frames, y0, sel, tables, output_nets, plan, valid, src):
@@ -233,17 +247,19 @@ def _score_frames_impl(
         #    2-of-3 majority vote reduces them before decode; a
         #    bit-sliced stack (src not None) routes through the word
         #    evaluator with the vote folded into the bitwise pass
-        outs, disagree = lut_ops.fabric_eval_bits_voted(
-            sel, tables, level_base, win_base, output_nets, bits,
-            n_replicas=n_replicas, n_inputs=n_inputs,
-            n_nets_pad=n_nets_pad, in_seg=in_seg,
-            batch_tile=batch_tile, interpret=interpret,
-            src=src)                                     # (Cl, B, O) uint8
+        with jax.named_scope("readout_fabric_eval"):
+            outs, disagree = lut_ops.fabric_eval_bits_voted(
+                sel, tables, level_base, win_base, output_nets, bits,
+                n_replicas=n_replicas, n_inputs=n_inputs,
+                n_nets_pad=n_nets_pad, in_seg=in_seg,
+                batch_tile=batch_tile, interpret=interpret,
+                src=src)                                 # (Cl, B, O) uint8
         # 5. score decode + trigger decision + SEU health counts — the
         #    SAME device tail as the features path's scoring dispatch
-        return lut_ops.decode_scores_device(
-            outs, disagree, plan["out_weight"], plan["threshold_raw"],
-            valid)
+        with jax.named_scope("readout_decode"):
+            return lut_ops.decode_scores_device(
+                outs, disagree, plan["out_weight"], plan["threshold_raw"],
+                valid)
 
     return jax.shard_map(
         body, mesh=mesh,
@@ -291,6 +307,13 @@ class FusedFrontend:
     def spec(self) -> FrontendSpec:
         """The feature-stage contract (StackGeometry.frontend metadata)."""
         return default_frontend_spec(self.threshold_electrons)
+
+    @staticmethod
+    def compiled_programs() -> int:
+        """Programs the fused step's jit holds, one per batch shape and
+        static flag set: a dispatch that grows it compiled a program (or
+        loaded one from the persistent compile cache)."""
+        return _score_frames._cache_size()
 
     def score_frames(
         self, frames, y0

@@ -70,6 +70,7 @@ def yprofile_pallas(
         out_specs=pl.BlockSpec((batch_tile, 128), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 128), jnp.float32),
         interpret=interpret,
+        name="yprofile",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(frames_flat, fold, y0_cols)
@@ -114,6 +115,7 @@ def yprofile_pallas_stacked(
         out_specs=pl.BlockSpec((1, batch_tile, 128), lambda c, b: (c, b, 0)),
         out_shape=jax.ShapeDtypeStruct((C, B, 128), jnp.float32),
         interpret=interpret,
+        name="yprofile",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(frames_flat, fold, y0_cols)

@@ -24,8 +24,8 @@ ingestion stages, one per deployment style:
       -> background config scrubbing  (optional: readback -> CRC verify ->
                                        heal of the served configuration
                                        memory, interleaved with dispatches)
-      -> per-chip trigger report      (rates, reduction, link budget,
-                                       per-stage host timing, per-replica
+      -> per-chip trigger report      (rates, reduction, link bytes,
+                                       per-stage host spans, per-replica
                                        SEU disagreement counters, scrub
                                        detections / healed bits / latency)
 
@@ -142,6 +142,7 @@ from repro.core.tmr import (
 )
 from repro.data.smartpixel import N_T, N_X, N_Y
 from repro.data.smartpixel import N_FEATURES as _N_FEATURES
+from repro.launch.spans import BatchRing, Stages
 from repro.parallel.compression import (
     DENSE_BYTES_PER_EVENT,
     SPARSE_BYTES_PER_EVENT,
@@ -347,7 +348,6 @@ class ServerConfig:
         prepares the next (2 = triple buffering, 1 = double buffering).
     threshold_electrons: per-pixel zero suppression of the frames->
         features stage (frames ingestion only).
-    bits_per_hit / hit_rate_hz: link-budget accounting for the report.
     deadline_us: per-event latency budget (enqueue -> drained result) in
         microseconds, or None (no deadline — latency is still measured,
         never acted on). With a deadline every drained event is scored
@@ -395,8 +395,6 @@ class ServerConfig:
     scrub_mode: str = "steered"
     pipeline_depth: int = 2
     threshold_electrons: float = 800.0
-    bits_per_hit: int = 256
-    hit_rate_hz: float = 40e6
     deadline_us: Optional[float] = None
     overload_policy: str = "observe"
     degrade_rungs: Tuple[str, ...] = DEGRADE_RUNGS
@@ -705,10 +703,19 @@ class ReadoutServer:
             ChipStreamStats(disagreements=[0] * self.n_replicas)
             for _ in self.chips
         ]
-        self._stage_s: Dict[str, float] = collections.defaultdict(float)
-        self._stage_n: Dict[str, int] = collections.defaultdict(int)
+        # per-stage host spans (seconds, calls, longest call), annotated
+        # into the profile while one is recording (launch/spans.py)
+        self._stages = Stages(clock)
+        self._batches_launched = 0   # the next batch's id
+        # dispatches of the fused step that grew its jit cache (compiled
+        # or loaded a program), and their seconds
+        self._compiles = 0
+        self._compile_s = 0.0
+        # the throughput window: first dispatch and last drain since the
+        # last reset, and the events drained in between
         self._t_start: Optional[float] = None
         self._t_last: Optional[float] = None
+        self._n_drained_window = 0
         self._n_scored = 0
         # measured host-link accounting: bytes actually materialized on
         # the wire (sparse packs when a batch drains sparse, dense rows
@@ -726,9 +733,10 @@ class ReadoutServer:
         self._hist_queue = LatencyHistogram()
         self._hist_service = LatencyHistogram()
         self._hist_chip = [LatencyHistogram() for _ in self.chips]
-        # the newest drained batch's monotonic stage timestamps
-        # (enqueue-oldest -> coalesce -> encode/stack -> launch -> drain)
-        self._last_batch_trace: Dict[str, float] = {}
+        # the newest drained batches' monotonic stage timestamps
+        # (enqueue-oldest -> coalesce -> encode/stack -> launch -> collect
+        # -> drain -> delivered), summarized as report()["latency"]["phases"]
+        self._ring = BatchRing()
         self._n_batches_drained = 0
 
         # ---- deadline enforcement state.
@@ -872,7 +880,19 @@ class ReadoutServer:
         or None when deadline admission control shed it (the shed is
         counted in the chip's ``n_shed``)."""
         assert 0 <= chip < self.n_chips, chip
-        now = self._clock()
+        with self._stages.span("admit", self._batches_launched) as sp:
+            return self._submit_features(chip, features, sp.t0)
+
+    def submit_batch(self, chip: int, X: np.ndarray) -> List[Optional[int]]:
+        """Enqueue a block of pre-featurized events (rows of X); shed
+        rows yield None in the returned seq list."""
+        assert 0 <= chip < self.n_chips, chip
+        with self._stages.span("admit", self._batches_launched):
+            return [self._submit_features(chip, row, self._clock())
+                    for row in np.asarray(X)]
+
+    def _submit_features(self, chip: int, features, now: float
+                         ) -> Optional[int]:
         if not self._admit(chip, now):
             return None
         seq = self._seq
@@ -881,11 +901,6 @@ class ReadoutServer:
             (seq, chip, "features", np.asarray(features, np.float64), now)
         )
         return seq
-
-    def submit_batch(self, chip: int, X: np.ndarray) -> List[Optional[int]]:
-        """Enqueue a block of pre-featurized events (rows of X); shed
-        rows yield None in the returned seq list."""
-        return [self.submit(chip, row) for row in np.asarray(X)]
 
     def cancel_queued(self, chip: int) -> int:
         """Drop every QUEUED (admitted, not yet coalesced) event of one
@@ -925,16 +940,17 @@ class ReadoutServer:
             frames.shape
         assert len(frames) == len(y0), (len(frames), len(y0))
         seqs: List[Optional[int]] = []
-        now = self._clock()
-        for i in range(len(frames)):
-            if not self._admit(chip, now):
-                seqs.append(None)
-                continue
-            seq = self._seq
-            self._seq += 1
-            self._queue.append(
-                (seq, chip, "frames", (frames[i], float(y0[i])), now))
-            seqs.append(seq)
+        with self._stages.span("admit", self._batches_launched) as sp:
+            now = sp.t0
+            for i in range(len(frames)):
+                if not self._admit(chip, now):
+                    seqs.append(None)
+                    continue
+                seq = self._seq
+                self._seq += 1
+                self._queue.append(
+                    (seq, chip, "frames", (frames[i], float(y0[i])), now))
+                seqs.append(seq)
         return seqs
 
     # ------------------------------------------------------------ the loop
@@ -950,6 +966,7 @@ class ReadoutServer:
         out = self._drain_ready()
         if self._due() and len(self._inflight) <= self.config.pipeline_depth:
             out.extend(self._dispatch(self._coalesce()))
+        self._ring.deliver_at(self._clock)
         return out
 
     def flush(self) -> List[ScoredEvent]:
@@ -968,12 +985,12 @@ class ReadoutServer:
                 out.extend(self._drain_one())       # flush MAY block
         out.extend(self._drain_all())
         if self.config.scrub_interval is not None:
-            t0 = self._clock()
-            self.scrub_flush()
-            if self.config.scrub_mode == "steered":
-                self._scrub_steered_check()
-                self.scrub_flush()      # device idle: resolve it now
-            self._stage("scrub", t0)
+            with self._stages.span("scrub", self._batches_launched - 1):
+                self.scrub_flush()
+                if self.config.scrub_mode == "steered":
+                    self._scrub_steered_check()
+                    self.scrub_flush()      # device idle: resolve it now
+        self._ring.deliver_at(self._clock)
         return out
 
     def score_stream(
@@ -1003,10 +1020,6 @@ class ReadoutServer:
     def _coalesce(self) -> List[_Event]:
         take = min(len(self._queue), self._eff_max_batch)
         return [self._queue.popleft() for _ in range(take)]
-
-    def _stage(self, key: str, t0: float) -> None:
-        self._stage_s[key] += self._clock() - t0
-        self._stage_n[key] += 1
 
     def _dispatch(self, events: List[_Event]) -> List[ScoredEvent]:
         """Launch one micro-batch and return any batches the pipeline
@@ -1118,25 +1131,36 @@ class ReadoutServer:
         until the drain (bit-sliced kernel launches never get here with
         sparse on: their pack is fused into the scoring jit, see
         ``_word_sparse_active``)."""
-        meta["trace"]["t_launched"] = self._clock()
         sparse = self._sparse_active()
         if not sparse:
+            meta["trace"]["t_launched"] = self._clock()
             return ("scored", (score, keep, disagree), per_chip_seq,
                     counts, meta)
-        t0 = self._clock()
         B = int(np.shape(keep)[1])
-        if self.config.backend == "kernel":
-            from repro.parallel.compression import sparse_trigger_pack_jit
+        with self._stages.span("sparse_pack", meta["batch"]):
+            if self.config.backend == "kernel":
+                from repro.parallel.compression import sparse_trigger_pack_jit
 
-            count, idx, vals = sparse_trigger_pack_jit(score, keep)
-        else:
-            flat = np.asarray(keep).ravel()
-            idx = np.flatnonzero(flat).astype(np.int32)
-            vals = np.asarray(score).ravel()[idx].astype(np.int32)
-            count = len(idx)
-        self._stage("sparse_pack", t0)
+                count, idx, vals = sparse_trigger_pack_jit(score, keep)
+            else:
+                flat = np.asarray(keep).ravel()
+                idx = np.flatnonzero(flat).astype(np.int32)
+                vals = np.asarray(score).ravel()[idx].astype(np.int32)
+                count = len(idx)
+        meta["trace"]["t_launched"] = self._clock()
         return ("sparse", (count, idx, vals, disagree, B),
                 per_chip_seq, counts, meta)
+
+    def _new_batch(self, events: List[_Event], B: int,
+                   per_chip_t: List[List[float]]) -> Dict:
+        """A coalesced batch's id and trace: the ledger ``_observe_batch``
+        folds and the row ``report()["latency"]["phases"]`` reads."""
+        bid = self._batches_launched
+        self._batches_launched += 1
+        trace = {"t_enqueued": min(e[4] for e in events),
+                 "t_coalesced": self._clock()}
+        return {"t_enq": per_chip_t, "trace": trace, "batch": bid,
+                "padded": B, "compiled": False}
 
     def _launch_features(self, events: List[_Event]) -> _Inflight:
         """Features path: host featurization (quantize + offset-binary bit
@@ -1146,54 +1170,49 @@ class ReadoutServer:
         (lut_eval.ops.fabric_eval_multi_scored), chip axis over the
         readout mesh."""
         per_chip_seq, per_chip_X, counts, per_chip_t = self._group(events)
-        trace = {"t_enqueued": min(e[4] for e in events),
-                 "t_coalesced": self._clock()}
-        meta = {"t_enq": per_chip_t, "trace": trace}
-
-        t0 = self._clock()
-        per_chip_bits: List[np.ndarray] = []
-        for i, chip in enumerate(self.chips):
-            if per_chip_X[i]:
-                bits = chip.encode_features(np.stack(per_chip_X[i]))
-            else:
-                bits = np.zeros((0, chip.config.n_inputs), np.uint8)
-            per_chip_bits.append(bits)
-        self._stage("encode_host", t0)
-        trace["t_encoded"] = self._clock()
-
-        t0 = self._clock()
         B = max(counts) if counts else 0
         if self.config.backend == "kernel":
             B = self._pad_batch(B)      # stable jit signatures (pow2)
-            lead = per_chip_bits[0]
-            if len(lead) < B:           # stack_event_bits pads to the max
-                per_chip_bits[0] = np.vstack(
-                    [lead, np.zeros((B - len(lead), lead.shape[1]),
-                                    np.uint8)])
+        meta = self._new_batch(events, B, per_chip_t)
+        trace, bid = meta["trace"], meta["batch"]
+
+        with self._stages.span("encode_host", bid) as sp:
+            per_chip_bits: List[np.ndarray] = []
+            for i, chip in enumerate(self.chips):
+                if per_chip_X[i]:
+                    bits = chip.encode_features(np.stack(per_chip_X[i]))
+                else:
+                    bits = np.zeros((0, chip.config.n_inputs), np.uint8)
+                per_chip_bits.append(bits)
+        trace["t_encoded"] = sp.t1
+
+        word_sparse = self._word_sparse_active()
+        with self._stages.span("launch_score", bid):
             valid = self._valid_mask(counts, B)
-            stacked = self._lut_ops.stack_input_bits(self._stack, per_chip_bits)
-            if self._word_sparse_active():
-                count, idx, vals, dis = (
-                    self._lut_ops.fabric_eval_multi_scored_sparse(
-                        self._stack, stacked, self._out_weight,
-                        self._thr_raw, valid=valid, mesh=self._mesh,
-                        batch_tile=self.config.batch_tile,
-                    ))  # async; keep cut + compaction fused in the jit
-                self._stage("launch_score", t0)
-                return self._finish_launch_sparse(
-                    count, idx, vals, dis, B, per_chip_seq, counts, meta)
-            score, keep, dis = self._lut_ops.fabric_eval_multi_scored(
-                self._stack, stacked, self._out_weight, self._thr_raw,
-                valid=valid, mesh=self._mesh,
-                batch_tile=self.config.batch_tile,
-            )  # async on device; NOT materialized yet
-        else:
-            valid = self._valid_mask(counts, B)
-            stacked = stack_event_bits(per_chip_bits, self.geometry.n_inputs)
-            score, keep, dis = self._score_bits_host(stacked, valid)
-        self._stage("launch_score", t0)
-        return self._finish_launch(score, keep, dis, per_chip_seq, counts,
-                                   meta)
+            if self.config.backend == "kernel":
+                lead = per_chip_bits[0]
+                if len(lead) < B:       # stack_event_bits pads to the max
+                    per_chip_bits[0] = np.vstack(
+                        [lead, np.zeros((B - len(lead), lead.shape[1]),
+                                        np.uint8)])
+                stacked = self._lut_ops.stack_input_bits(
+                    self._stack, per_chip_bits)
+                # async on device, NOT materialized yet; the word-sparse
+                # form fuses the keep cut and the compaction into the jit
+                score = (self._lut_ops.fabric_eval_multi_scored_sparse
+                         if word_sparse
+                         else self._lut_ops.fabric_eval_multi_scored)
+                out = score(self._stack, stacked, self._out_weight,
+                            self._thr_raw, valid=valid, mesh=self._mesh,
+                            batch_tile=self.config.batch_tile)
+            else:
+                stacked = stack_event_bits(per_chip_bits,
+                                           self.geometry.n_inputs)
+                out = self._score_bits_host(stacked, valid)
+        if word_sparse:
+            return self._finish_launch_sparse(
+                *out, B, per_chip_seq, counts, meta)
+        return self._finish_launch(*out, per_chip_seq, counts, meta)
 
     def _score_bits_host(
         self, stacked: np.ndarray, valid: np.ndarray
@@ -1231,39 +1250,42 @@ class ReadoutServer:
         the breakdown the fused path removes.
         """
         per_chip_seq, per_chip_fy, counts, per_chip_t = self._group(events)
-        trace = {"t_enqueued": min(e[4] for e in events),
-                 "t_coalesced": self._clock()}
-        meta = {"t_enq": per_chip_t, "trace": trace}
         cfg = self.config
         B = max(counts) if counts else 0
         if cfg.backend == "kernel":
             B = self._pad_batch(B)      # stable jit signatures (pow2)
+        meta = self._new_batch(events, B, per_chip_t)
+        bid = meta["batch"]
         valid = self._valid_mask(counts, B)
 
         if cfg.backend == "kernel":
-            t0 = self._clock()
-            frames = np.zeros((self.n_chips, B, N_T, N_Y, N_X), np.float32)
-            y0 = np.zeros((self.n_chips, B), np.float32)
-            for i, rows in enumerate(per_chip_fy):
-                if rows:  # one vectorized copy per chip, not per event
-                    frames[i, : len(rows)] = np.stack([fr for fr, _ in rows])
-                    y0[i, : len(rows)] = [z for _, z in rows]
-            self._stage("stack_frames", t0)
-            trace["t_encoded"] = self._clock()
+            with self._stages.span("stack_frames", bid) as sp:
+                frames = np.zeros((self.n_chips, B, N_T, N_Y, N_X),
+                                  np.float32)
+                y0 = np.zeros((self.n_chips, B), np.float32)
+                for i, rows in enumerate(per_chip_fy):
+                    if rows:  # one vectorized copy per chip, not per event
+                        frames[i, : len(rows)] = np.stack(
+                            [fr for fr, _ in rows])
+                        y0[i, : len(rows)] = [z for _, z in rows]
+            meta["trace"]["t_encoded"] = sp.t1
 
-            t0 = self._clock()
-            if self._word_sparse_active():
-                count, idx, vals, dis = (
-                    self._get_frontend().score_frames_sparse(
-                        frames, y0, valid=valid))
-                self._stage("launch_fused", t0)
+            frontend = self._get_frontend()
+            word_sparse = self._word_sparse_active()
+            score = (frontend.score_frames_sparse if word_sparse
+                     else frontend.score_frames_voted)
+            n_programs = frontend.compiled_programs()
+            with self._stages.span("launch_fused", bid) as sp:
+                out = score(frames, y0, valid=valid)
+            if frontend.compiled_programs() > n_programs:
+                # this dispatch compiled (or loaded) a program
+                self._compiles += 1
+                self._compile_s += sp.t1 - sp.t0
+                meta["compiled"] = True
+            if word_sparse:
                 return self._finish_launch_sparse(
-                    count, idx, vals, dis, B, per_chip_seq, counts, meta)
-            score, keep, dis = self._get_frontend().score_frames_voted(
-                frames, y0, valid=valid)
-            self._stage("launch_fused", t0)
-            return self._finish_launch(score, keep, dis, per_chip_seq,
-                                       counts, meta)
+                    *out, B, per_chip_seq, counts, meta)
+            return self._finish_launch(*out, per_chip_seq, counts, meta)
 
         # host backend: staged oracle, per chip, one sim per replica
         R = self.n_replicas
@@ -1275,32 +1297,29 @@ class ReadoutServer:
             n = counts[i]
             frames_i = np.stack([fr for fr, _ in per_chip_fy[i]])
             y0_i = np.asarray([z for _, z in per_chip_fy[i]], np.float32)
-            t0 = self._clock()
             from repro.kernels.yprofile import ops as yp_ops
 
-            feats = np.asarray(yp_ops.yprofile(
-                frames_i, y0_i, threshold_electrons=cfg.threshold_electrons,
-                batch_tile=cfg.batch_tile))
-            self._stage("staged_featurize", t0)
-            t0 = self._clock()
-            bits = chip.encode_features(feats)
-            self._stage("staged_encode", t0)
-            t0 = self._clock()
-            if self._frame_sims[i] is None:
-                self._frame_sims[i] = [
-                    FabricSim(self._replica_configs[i * R + r])
-                    for r in range(R)
-                ]
-            g = np.stack(
-                [np.asarray(sim.run(bits)[0]) for sim in self._frame_sims[i]]
-            )                                           # (R, n, O_i)
-            if R > 1:
-                voted = majority_vote(g[0], g[1], g[2])
-                disagree[i, :, :n] = (g != voted[None]).any(-1)
-            else:
-                voted = g[0]
-            score[i, :n] = chip.synth.decode_outputs(voted)
-            self._stage("staged_score", t0)
+            with self._stages.span("staged_featurize", bid):
+                feats = np.asarray(yp_ops.yprofile(
+                    frames_i, y0_i,
+                    threshold_electrons=cfg.threshold_electrons,
+                    batch_tile=cfg.batch_tile))
+            with self._stages.span("staged_encode", bid):
+                bits = chip.encode_features(feats)
+            with self._stages.span("staged_score", bid):
+                if self._frame_sims[i] is None:
+                    self._frame_sims[i] = [
+                        FabricSim(self._replica_configs[i * R + r])
+                        for r in range(R)
+                    ]
+                g = np.stack([np.asarray(sim.run(bits)[0])
+                              for sim in self._frame_sims[i]])  # (R, n, O_i)
+                if R > 1:
+                    voted = majority_vote(g[0], g[1], g[2])
+                    disagree[i, :, :n] = (g != voted[None]).any(-1)
+                else:
+                    voted = g[0]
+                score[i, :n] = chip.synth.decode_outputs(voted)
         keep = (score <= self._thr_raw[:, None]) & valid
         dis = (disagree & valid[:, None, :]).sum(-1).astype(np.int64)
         return self._finish_launch(score, keep, dis, per_chip_seq, counts,
@@ -1362,46 +1381,46 @@ class ReadoutServer:
         if not self._inflight:
             return []
         kind, pending, per_chip_seq, counts, meta = self._inflight.popleft()
-        t0 = self._clock()
-
         results: List[ScoredEvent] = []
         n_events = int(sum(counts))
-        if kind == "sparse":
-            count, idx, vals, dis, B = pending
-            n_kept = int(np.asarray(count))             # blocks here
-            idx_h = np.asarray(idx[:n_kept]).astype(np.int64)
-            vals_h = np.asarray(vals[:n_kept]).astype(np.int64)
-            self._link_bytes_wire += (
-                SPARSE_HEADER_BYTES + SPARSE_BYTES_PER_EVENT * n_kept)
-            self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
-            kept_per_chip = np.bincount(
-                idx_h // max(B, 1), minlength=self.n_chips)
-            for i, st in enumerate(self._stats):
-                st.n_in += counts[i]
-                st.n_kept += int(kept_per_chip[i])
-            for k, v in zip(idx_h, vals_h):
-                chip, pos = int(k) // B, int(k) % B
-                results.append(ScoredEvent(
-                    seq=per_chip_seq[chip][pos], chip=chip,
-                    score_raw=int(v), keep=True))
-            self._fold_disagreements(dis)
-        else:  # "scored"
-            score, keep, dis = pending
-            score = np.asarray(score)                   # blocks here
-            keep = np.asarray(keep)
-            self._link_bytes_wire += DENSE_BYTES_PER_EVENT * n_events
-            self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
-            for i in range(self.n_chips):
-                n = counts[i]
-                if not n:
-                    continue
-                self._fold_chip(results, i, per_chip_seq[i],
-                                score[i, :n].astype(np.int64), keep[i, :n])
-            self._fold_disagreements(dis)
-
-        self._stage("drain_wait", t0)
+        with self._stages.span("drain_wait", meta["batch"]) as sp:
+            if kind == "sparse":
+                count, idx, vals, dis, B = pending
+                n_kept = int(np.asarray(count))             # blocks here
+                idx_h = np.asarray(idx[:n_kept]).astype(np.int64)
+                vals_h = np.asarray(vals[:n_kept]).astype(np.int64)
+                self._link_bytes_wire += (
+                    SPARSE_HEADER_BYTES + SPARSE_BYTES_PER_EVENT * n_kept)
+                self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
+                kept_per_chip = np.bincount(
+                    idx_h // max(B, 1), minlength=self.n_chips)
+                for i, st in enumerate(self._stats):
+                    st.n_in += counts[i]
+                    st.n_kept += int(kept_per_chip[i])
+                for k, v in zip(idx_h, vals_h):
+                    chip, pos = int(k) // B, int(k) % B
+                    results.append(ScoredEvent(
+                        seq=per_chip_seq[chip][pos], chip=chip,
+                        score_raw=int(v), keep=True))
+                self._fold_disagreements(dis)
+            else:  # "scored"
+                score, keep, dis = pending
+                score = np.asarray(score)                   # blocks here
+                keep = np.asarray(keep)
+                self._link_bytes_wire += DENSE_BYTES_PER_EVENT * n_events
+                self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
+                for i in range(self.n_chips):
+                    n = counts[i]
+                    if not n:
+                        continue
+                    self._fold_chip(results, i, per_chip_seq[i],
+                                    score[i, :n].astype(np.int64),
+                                    keep[i, :n])
+                self._fold_disagreements(dis)
         self._n_scored += len(results)
-        t_done = self._clock()
+        self._n_drained_window += n_events
+        meta["trace"]["t_collect"] = sp.t0
+        t_done = sp.t1
         self._t_last = t_done
         self._observe_batch(meta, t_done)
         results.sort(key=lambda r: r.seq)
@@ -1409,8 +1428,9 @@ class ReadoutServer:
 
     # ------------------------------------------- latency / deadline loop
     def reset_latency_metrics(self) -> None:
-        """Zero the latency/deadline ledger (histograms, met/missed/shed
-        counters, the EWMA seed and the throughput window) without
+        """Zero the latency/deadline ledger (histograms, the batch phase
+        ring, the stages' longest calls, the compile counter, met/missed/
+        shed counters, the EWMA seed and the throughput window) without
         touching trigger accounting, scrub state or the ladder level —
         for measuring a warmed-up server: jit compilation of the first
         dispatch otherwise dominates every percentile of a short run."""
@@ -1418,7 +1438,11 @@ class ReadoutServer:
         self._hist_queue = LatencyHistogram()
         self._hist_service = LatencyHistogram()
         self._hist_chip = [LatencyHistogram() for _ in self.chips]
-        self._last_batch_trace = {}
+        self._ring.reset()
+        self._stages.reset_max()
+        self._compiles = 0
+        self._compile_s = 0.0
+        self._n_drained_window = 0
         self._n_batches_drained = 0
         self._deadline_met = 0
         self._deadline_missed = 0
@@ -1442,7 +1466,6 @@ class ReadoutServer:
         traffic admission control let through."""
         trace = meta["trace"]
         trace["t_drained"] = t_done
-        self._last_batch_trace = trace
         self._n_batches_drained += 1
         t_co = trace.get("t_coalesced", t_done)
         dl = self.config.deadline_s
@@ -1466,6 +1489,8 @@ class ReadoutServer:
                 self._deadline_met += len(ts) - missed
                 self._window_missed += missed
         self._hist_service.add(max(t_done - t_co, 0.0) * 1e6)
+        self._ring.record(meta["batch"], n_batch, meta["padded"], trace,
+                          meta["compiled"])
         self._window_drained += n_batch
         # EWMA of the batch service time — the admission controller's
         # look-ahead: how long will a newly admitted event take AFTER
@@ -1775,39 +1800,38 @@ class ReadoutServer:
         verifies in place. Returns one record per healed frame:
         {"slot", "replica", "healed_bits", "detection_latency_dispatches"}.
         """
-        t0 = self._clock()
-        healed: List[Dict[str, int]] = []
-        # resolve readbacks whose device->host copies have completed —
-        # and ONLY those: with a short interval the sampled batch can
-        # still be in flight behind the pipeline, and blocking on it
-        # here would stall exactly the overlap scrubbing must not touch.
-        # A copy that never reports ready is force-resolved once the
-        # queue exceeds one full frame cycle (bounded staleness).
-        n_frames = self.n_chips * self.n_replicas
-        still_pending = collections.deque()
-        while self._scrub_pending:
-            entry = self._scrub_pending.popleft()
-            arr = entry[2]
-            ready = not hasattr(arr, "is_ready") or arr.is_ready()
-            if ready or len(self._scrub_pending) >= n_frames:
-                rec = self._resolve_readback(*entry)
-                if rec:
-                    healed.append(rec)
-            else:
-                still_pending.append(entry)
-        self._scrub_pending = still_pending
-        R = self.n_replicas
-        if self.config.scrub_mode == "steered":
-            healed.extend(self._scrub_steered_check())
-        f = self._scrub_rr
-        self._scrub_rr = (f + 1) % n_frames
-        if self._scrub_rr == 0:
-            self._scrub_cycles += 1
-        rec = self._issue_scrub(f // R, f % R)
-        if rec:
-            healed.append(rec)
-        self._scrub_steps += 1
-        self._stage("scrub", t0)
+        with self._stages.span("scrub", self._batches_launched - 1):
+            healed: List[Dict[str, int]] = []
+            # resolve readbacks whose device->host copies have completed —
+            # and ONLY those: with a short interval the sampled batch can
+            # still be in flight behind the pipeline, and blocking on it
+            # here would stall exactly the overlap scrubbing must not touch.
+            # A copy that never reports ready is force-resolved once the
+            # queue exceeds one full frame cycle (bounded staleness).
+            n_frames = self.n_chips * self.n_replicas
+            still_pending = collections.deque()
+            while self._scrub_pending:
+                entry = self._scrub_pending.popleft()
+                arr = entry[2]
+                ready = not hasattr(arr, "is_ready") or arr.is_ready()
+                if ready or len(self._scrub_pending) >= n_frames:
+                    rec = self._resolve_readback(*entry)
+                    if rec:
+                        healed.append(rec)
+                else:
+                    still_pending.append(entry)
+            self._scrub_pending = still_pending
+            R = self.n_replicas
+            if self.config.scrub_mode == "steered":
+                healed.extend(self._scrub_steered_check())
+            f = self._scrub_rr
+            self._scrub_rr = (f + 1) % n_frames
+            if self._scrub_rr == 0:
+                self._scrub_cycles += 1
+            rec = self._issue_scrub(f // R, f % R)
+            if rec:
+                healed.append(rec)
+            self._scrub_steps += 1
         return healed
 
     def scrub_flush(self) -> List[Dict[str, int]]:
@@ -1946,8 +1970,8 @@ class ReadoutServer:
     # ------------------------------------------------------------ report
     def report(self) -> Dict[str, object]:
         """Per-chip trigger/reduction accounting aggregated over the
-        stream, plus the per-stage host-side timing breakdown (seconds and
-        call counts per pipeline stage — for fused frames dispatches the
+        stream, plus the per-stage host spans (seconds, calls and the
+        longest call per pipeline stage — for fused frames dispatches the
         featurize/quantize/pack/vote/score stages are a single
         ``launch_fused`` entry by design; the staged host path itemizes
         them), the per-replica SEU disagreement counters, the measured
@@ -1955,7 +1979,9 @@ class ReadoutServer:
         accounting (steps/cycles/frames, CRC detections, healed config
         bits, per-detection latency in dispatches). The deadline-aware
         additions: per-chip and total latency histograms (p50/p99/p99.9
-        + CDF), the last drained batch's stage trace, the met/missed/
+        + CDF), the last drained batch's stage trace and the phases of
+        the newest batches (``latency.phases``), the fused-step dispatches
+        that compiled while serving (``compiles``), the met/missed/
         shed deadline ledger, the adaptive coalescer's effective knobs,
         and the degrade ladder's level + timestamped transitions. With a
         network front door attached (net/ingress.py), ``"net"`` carries
@@ -1973,9 +1999,6 @@ class ReadoutServer:
                 "n_shed": st.n_shed,
                 "fraction_kept": frac,
                 "data_reduction_factor": 1.0 / max(frac, 1e-9),
-                "link_rate_in_gbps": cfg.hit_rate_hz * cfg.bits_per_hit / 1e9,
-                "link_rate_out_gbps":
-                    cfg.hit_rate_hz * cfg.bits_per_hit * frac / 1e9,
                 "seu_disagreements": list(st.disagreements),
                 "latency_p99_us": self._hist_chip[i].percentile(99.0),
             })
@@ -1986,10 +2009,11 @@ class ReadoutServer:
             if (self._t_start is not None and self._t_last is not None)
             else 0.0
         )
-        t_base = self._last_batch_trace.get("t_enqueued")
+        newest = self._ring.newest() or {}
+        t_base = newest.get("t_enqueued")
         trace_us = {
-            k: (v - t_base) * 1e6
-            for k, v in self._last_batch_trace.items()
+            k: (v - t_base) * 1e6 for k, v in newest.items()
+            if k.startswith("t_") and math.isfinite(v)
         } if t_base is not None else {}
         n_shed = sum(s.n_shed for s in self._stats)
         return {
@@ -2006,7 +2030,8 @@ class ReadoutServer:
             "n_in": n_in,
             "n_kept": n_kept,
             "fraction_kept": n_kept / n_in if n_in else 1.0,
-            "events_per_s": n_in / dt if dt > 0 else float("nan"),
+            "events_per_s": (self._n_drained_window / dt if dt > 0
+                             else float("nan")),
             "queue_depth": self.queue_depth,
             "inflight_batches": len(self._inflight),
             "seu_disagreement_total": int(
@@ -2042,7 +2067,10 @@ class ReadoutServer:
                 "service": self._hist_service.summary(),
                 "cdf_us": self._hist_total.cdf(),
                 "last_batch_trace_us": trace_us,
+                "phases": self._ring.summary(),
             },
+            "compiles": {"dispatches": self._compiles,
+                         "seconds": self._compile_s},
             "deadline": {
                 "deadline_us": cfg.deadline_us,
                 "policy": cfg.overload_policy,
@@ -2066,10 +2094,7 @@ class ReadoutServer:
                     "deferred_heals_pending": len(self._deferred_heals),
                 },
             },
-            "stages": {
-                k: {"seconds": self._stage_s[k], "calls": self._stage_n[k]}
-                for k in sorted(self._stage_s)
-            },
+            "stages": self._stages.report(),
             "net": (self._net_stats_provider()
                     if self._net_stats_provider is not None
                     else {"attached": False}),
