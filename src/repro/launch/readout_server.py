@@ -83,6 +83,14 @@ Key properties:
     dispatches are DEFERRED — backlog accumulates in the submit queue
     where admission control can see (and shed) it, instead of silently
     backpressuring the caller. Only ``flush()`` blocks.
+  * Staging in place (kernel frames path): a dispatch writes its frames
+    into a reused host arena (launch/staging.py) and zeroes only the pad
+    rows past each chip's count; nothing batch-sized is allocated,
+    zero-filled or freed per dispatch. An arena returns to the free list
+    when its batch drains, never while the transfer or step may still
+    read it. The cost is resident host memory: at most
+    ``pipeline_depth + 2`` arenas, each the size of the largest batch
+    seen (4 x 71.6 MB for 4 chips x 2,048 frames at the default depth).
   * Deadline-aware serving: the trigger chain gives every event a hard
     latency budget — data that misses the window is physics lost, so
     overload must degrade gracefully instead of queueing unboundedly.
@@ -143,6 +151,7 @@ from repro.core.tmr import (
 from repro.data.smartpixel import N_T, N_X, N_Y
 from repro.data.smartpixel import N_FEATURES as _N_FEATURES
 from repro.launch.spans import BatchRing, Stages
+from repro.launch.staging import StagingArenas
 from repro.parallel.compression import (
     DENSE_BYTES_PER_EVENT,
     SPARSE_BYTES_PER_EVENT,
@@ -699,6 +708,10 @@ class ReadoutServer:
             [None] * len(self.chips))
         # the pipeline: up to config.pipeline_depth batches on the device
         self._inflight: Deque[_Inflight] = collections.deque()
+        # host staging buffers of the kernel frames path (launch/staging.py):
+        # a batch is staged only while pipeline_depth or fewer are in
+        # flight, so pipeline_depth + 1 arenas serve; one more is slack
+        self._arenas = StagingArenas(config.pipeline_depth + 2)
         self._stats = [
             ChipStreamStats(disagreements=[0] * self.n_replicas)
             for _ in self.chips
@@ -1260,15 +1273,21 @@ class ReadoutServer:
 
         if cfg.backend == "kernel":
             with self._stages.span("stack_frames", bid) as sp:
-                frames = np.zeros((self.n_chips, B, N_T, N_Y, N_X),
-                                  np.float32)
+                # rows are written in place into a reused arena; only the
+                # pad rows past each chip's count are zeroed
+                shape = (self.n_chips, B, N_T, N_Y, N_X)
+                size = math.prod(shape)
+                arena = self._arenas.take(size)
+                frames = arena[:size].reshape(shape)
                 y0 = np.zeros((self.n_chips, B), np.float32)
                 for i, rows in enumerate(per_chip_fy):
-                    if rows:  # one vectorized copy per chip, not per event
-                        frames[i, : len(rows)] = np.stack(
-                            [fr for fr, _ in rows])
-                        y0[i, : len(rows)] = [z for _, z in rows]
+                    n = len(rows)
+                    if n:
+                        np.stack([fr for fr, _ in rows], out=frames[i, :n])
+                        y0[i, :n] = [z for _, z in rows]
+                    frames[i, n:] = 0.0
             meta["trace"]["t_encoded"] = sp.t1
+            meta["arena"] = arena
 
             frontend = self._get_frontend()
             word_sparse = self._word_sparse_active()
@@ -1417,6 +1436,9 @@ class ReadoutServer:
                                     score[i, :n].astype(np.int64),
                                     keep[i, :n])
                 self._fold_disagreements(dis)
+        # the results have materialized, so the step that read the staged
+        # frames is done: their arena may take the next batch
+        self._arenas.give(meta.get("arena"))
         self._n_scored += len(results)
         self._n_drained_window += n_events
         meta["trace"]["t_collect"] = sp.t0
@@ -1429,8 +1451,9 @@ class ReadoutServer:
     # ------------------------------------------- latency / deadline loop
     def reset_latency_metrics(self) -> None:
         """Zero the latency/deadline ledger (histograms, the batch phase
-        ring, the stages' longest calls, the compile counter, met/missed/
-        shed counters, the EWMA seed and the throughput window) without
+        ring, the stages' longest calls, the compile counter, the staging
+        arenas' reused/fresh counts, met/missed/shed counters, the EWMA
+        seed and the throughput window) without
         touching trigger accounting, scrub state or the ladder level —
         for measuring a warmed-up server: jit compilation of the first
         dispatch otherwise dominates every percentile of a short run."""
@@ -1442,6 +1465,7 @@ class ReadoutServer:
         self._stages.reset_max()
         self._compiles = 0
         self._compile_s = 0.0
+        self._arenas.reset_counts()
         self._n_drained_window = 0
         self._n_batches_drained = 0
         self._deadline_met = 0
@@ -1981,7 +2005,10 @@ class ReadoutServer:
         additions: per-chip and total latency histograms (p50/p99/p99.9
         + CDF), the last drained batch's stage trace and the phases of
         the newest batches (``latency.phases``), the fused-step dispatches
-        that compiled while serving (``compiles``), the met/missed/
+        that compiled while serving (``compiles``), the kernel frames
+        path's staging arenas (``staging``: dispatches that ``reused`` an
+        arena or allocated a ``fresh`` buffer since the reset, the
+        ``arenas`` alive and their ``resident_bytes``), the met/missed/
         shed deadline ledger, the adaptive coalescer's effective knobs,
         and the degrade ladder's level + timestamped transitions. With a
         network front door attached (net/ingress.py), ``"net"`` carries
@@ -2071,6 +2098,7 @@ class ReadoutServer:
             },
             "compiles": {"dispatches": self._compiles,
                          "seconds": self._compile_s},
+            "staging": self._arenas.report(),
             "deadline": {
                 "deadline_us": cfg.deadline_us,
                 "policy": cfg.overload_policy,
