@@ -1,6 +1,6 @@
-"""The readers of the server's own spans, phases and counters: each reads
-its value from a record that holds it, and nothing from a record of a
-program without them."""
+"""The readers of the server's own spans and phases: each reads its value
+from a record that holds it, and nothing from a record of a program
+without them."""
 from __future__ import annotations
 
 import pytest
@@ -30,8 +30,6 @@ def record():
                 "batches": 5, "dropped": 0, "compiled_batches": 0,
                 "staging": phase(3100.0), "collect_wait": phase(2500.0),
                 "drain": phase(400.0), "handoff": phase(1200.0)}},
-            "staging": {"reused": 3, "fresh": 1, "arenas": 3,
-                        "resident_bytes": 3 * 71_565_312},
         },
     }
 
@@ -54,7 +52,6 @@ READINGS = [
     ("collect_wait_ms_p50.steady", 2.5),
     ("handoff_ms_p50.steady", 1.2),
     ("stage_max_ms.steady", 125.0),
-    ("staging_reuse_pct", 75.0),
 ]
 
 
@@ -75,15 +72,3 @@ def test_reader_reads_nothing_from_an_empty_window(name):
     rec["events_submitted"] = 0
     rec["report"]["latency"]["phases"]["staging"]["count"] = 0
     assert spec.load_reader(name)(rec) is None
-
-
-def test_staging_reuse_reads_nothing_without_a_dispatch():
-    rec = record()
-    rec["report"]["staging"].update(reused=0, fresh=0)
-    assert spec.load_reader("staging_reuse_pct")(rec) is None
-
-
-def test_staging_reuse_reads_100_when_every_dispatch_reused():
-    rec = record()
-    rec["report"]["staging"].update(reused=25, fresh=0)
-    assert spec.load_reader("staging_reuse_pct")(rec) == 100.0
