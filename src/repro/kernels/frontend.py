@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -277,6 +277,28 @@ _score_frames = functools.partial(
 )(_score_frames_impl)
 
 
+def _place_plan(plan: Dict, mesh: Mesh) -> Dict[str, jax.Array]:
+    """The encode plan's (C, ...) rows, each on the device that serves
+    its chip, as the dispatch's ``shard_map`` splits them."""
+    return jax.device_put(plan, NamedSharding(mesh, P("chips")))
+
+
+class PlacedFrames(NamedTuple):
+    """One batch on the mesh (``FusedFrontend.place``): the tile-padded
+    frames, y0 and valid mask, each sharded over the "chips" axis, and
+    ``batch``, the caller's batch width B before the tile padding."""
+
+    frames: jax.Array           # (C, Bp, T, Y, X) f32
+    y0: jax.Array               # (C, Bp) f32
+    valid: jax.Array            # (C, Bp) bool
+    batch: int
+
+    @property
+    def width(self) -> int:
+        """Bp: event rows placed per chip."""
+        return self.frames.shape[1]
+
+
 @dataclasses.dataclass(frozen=True)
 class FusedFrontend:
     """N configured chips' whole frontends, one sharded device dispatch.
@@ -336,10 +358,8 @@ class FusedFrontend:
         against. All-zero on a healthy (or non-redundant) stack.
 
         ``frames``/``y0``/``valid`` are host arrays; the dispatch places
-        its own copies on the mesh."""
-        score, keep, dis = self._dispatch(frames, y0, valid, sparse=False)
-        B = np.shape(frames)[1]
-        return score[:, :B], keep[:, :B], dis
+        its own copies on the mesh (``place``, then ``score_placed``)."""
+        return self.score_placed(self.place(frames, y0, valid))
 
     def score_frames_sparse(
         self, frames, y0, valid=None
@@ -357,19 +377,12 @@ class FusedFrontend:
         are NOT materialized; slice ``idx[:count]`` on device before
         np.asarray to ship exactly the kept events (the server's drain
         does)."""
-        C, B = np.shape(frames)[0], np.shape(frames)[1]
-        count, idx, vals, dis = self._dispatch(frames, y0, valid,
-                                               sparse=True)
-        Bp = -(-max(B, 1) // self.batch_tile) * self.batch_tile
-        if Bp != B:
-            # Kept lanes sit below B (``valid`` kills the pad tail):
-            # restride tile-padded flat indices to the caller's batch.
-            idx = jnp.where(idx >= 0, (idx // Bp) * B + (idx % Bp), -1)
-            idx = idx[: C * B]
-            vals = vals[: C * B]
-        return count, idx, vals, dis
+        return self.score_placed(self.place(frames, y0, valid), sparse=True)
 
-    def _dispatch(self, frames, y0, valid, *, sparse: bool):
+    def place(self, frames, y0, valid=None) -> PlacedFrames:
+        """The first half of a dispatch: pad the host batch to the tile
+        and put it on the mesh, each chip-axis shard on its own device.
+        ``valid`` (C, B) bool marks the real event rows (None = all)."""
         frames = np.asarray(frames, np.float32)
         y0 = np.asarray(y0, np.float32)
         C, B = frames.shape[0], frames.shape[1]
@@ -388,15 +401,42 @@ class FusedFrontend:
         # sharded dispatch would then have to redistribute.
         frames, y0, valid = jax.device_put(
             (frames, y0, valid), NamedSharding(self.mesh, P("chips")))
+        return PlacedFrames(frames, y0, valid, B)
+
+    def score_placed(self, placed: PlacedFrames, *, sparse: bool = False):
+        """The second half of a dispatch: the fused step on a placed
+        batch. Returns what ``score_frames_voted`` (or, with ``sparse``,
+        ``score_frames_sparse``) returns for the caller's batch width."""
         s = self.stack
-        return _score_frames(
-            frames, y0, s.sel, s.tables, s.level_base, s.win_base,
-            s.output_nets, self.plan, valid, s.src,
+        out = _score_frames(
+            placed.frames, placed.y0, s.sel, s.tables, s.level_base,
+            s.win_base, s.output_nets, self.plan, placed.valid, s.src,
             mesh=self.mesh, n_replicas=s.n_replicas,
             threshold_electrons=self.threshold_electrons,
             n_inputs=s.n_inputs, in_seg=s.in_seg, n_nets_pad=s.n_nets_pad,
             batch_tile=self.batch_tile, interpret=self.interpret,
             sparse=sparse)
+        B, Bp = placed.batch, placed.width
+        if not sparse:
+            score, keep, dis = out
+            return score[:, :B], keep[:, :B], dis
+        count, idx, vals, dis = out
+        if Bp != B:
+            # Kept lanes sit below B (``valid`` kills the pad tail):
+            # restride tile-padded flat indices to the caller's batch.
+            C = placed.frames.shape[0]
+            idx = jnp.where(idx >= 0, (idx // Bp) * B + (idx % Bp), -1)
+            idx = idx[: C * B]
+            vals = vals[: C * B]
+        return count, idx, vals, dis
+
+    def on_mesh(self, mesh: Mesh,
+                stack: lut_ops.PackedFabricStack) -> "FusedFrontend":
+        """This frontend moved to ``mesh``, serving ``stack`` (already
+        placed there, ``PackedFabricStack.on_mesh``); the encode plan's
+        rows go to the devices that serve their chips."""
+        return dataclasses.replace(
+            self, stack=stack, mesh=mesh, plan=_place_plan(self.plan, mesh))
 
     def swap_chip(
         self, slot: int, config: FabricConfig, chip_spec: ChipFrontendSpec,
@@ -453,9 +493,12 @@ def pack_frontend(
     into the bitwise pass); ``batch_tile`` is also the featurizer tile, so the
     staged comparison path must featurize with the same tile to stay
     bit-identical (ScoringBackend.score_frames does). ``mesh`` defaults
-    to launch.mesh.make_readout_mesh(len(configs)). A caller that already
-    packed the configs (the readout server's lut_eval stack) shares the
-    arrays via ``stack`` instead of packing them a second time.
+    to launch.mesh.make_readout_mesh(len(configs)); the encode plan, and
+    the stack when packed here, are placed on it once, each chip's rows
+    on the device that serves it (``PackedFabricStack.on_mesh``). A
+    caller that already packed the configs (the readout server's
+    lut_eval stack) shares the arrays via ``stack``, placed by that
+    caller, instead of packing them a second time.
 
     ``redundancy="tmr"`` serves every chip as three placement-distinct
     replica encodings voted on device (see lut_eval.ops.pack_fabrics);
@@ -467,9 +510,11 @@ def pack_frontend(
     n_features = default_frontend_spec(threshold_electrons).n_features
     for config, cs in zip(configs, chip_specs):
         validate_chip_frontend(config, cs, n_features)
+    mesh = mesh if mesh is not None else make_readout_mesh(len(configs))
     if stack is None:
         stack = lut_ops.pack_fabrics(
-            list(configs), band=band, redundancy=redundancy, layout=layout)
+            list(configs), band=band, redundancy=redundancy,
+            layout=layout).on_mesh(mesh)
     elif redundancy != "none" and stack.n_replicas == 1:
         raise ValueError(
             f"redundancy={redundancy!r} but the shared stack is not "
@@ -485,8 +530,8 @@ def pack_frontend(
     return FusedFrontend(
         stack=stack,
         chip_specs=tuple(chip_specs),
-        plan=plan,
-        mesh=mesh if mesh is not None else make_readout_mesh(len(configs)),
+        plan=_place_plan(plan, mesh),
+        mesh=mesh,
         batch_tile=batch_tile,
         threshold_electrons=float(threshold_electrons),
         interpret=default_interpret() if interpret is None else interpret,

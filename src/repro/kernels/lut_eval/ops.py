@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.fabric import (
     FabricConfig,
@@ -187,6 +187,23 @@ class PackedFabricStack:
     @property
     def redundant(self) -> bool:
         return self.n_replicas > 1
+
+    def on_mesh(self, mesh: Mesh) -> "PackedFabricStack":
+        """This stack placed for the sharded dispatches on ``mesh``: each
+        per-slot array row-sharded over "chips", as the dispatches'
+        ``shard_map`` splits it, so every device holds its own chips'
+        tables and routing; the shared (L,) arrays replicated. Placed once
+        (at construction and on a mesh rebind), a dispatch moves only its
+        batch; a hot swap keeps the placement."""
+        chips = NamedSharding(mesh, P("chips"))
+        shared = NamedSharding(mesh, P())
+        put = lambda x, to: None if x is None else jax.device_put(x, to)
+        return dataclasses.replace(
+            self, sel=put(self.sel, chips), src=put(self.src, chips),
+            tables=put(self.tables, chips),
+            output_nets=put(self.output_nets, chips),
+            level_base=put(self.level_base, shared),
+            win_base=put(self.win_base, shared))
 
     def _envelope(self) -> StackGeometry:
         return StackGeometry(

@@ -29,15 +29,14 @@ exists:
   returns exactly as verified. ``retire`` discards the golden image;
   subsequent requests raise the named ``GoldenSlotError``.
 
-* **Grow/shrink** (launch.mesh.make_fleet_meshes + train.elastic.
-  reshard_replicated): buckets are created on demand (``admit`` /
-  ``prewarm``) and retired when empty (``shrink``); after every
-  resize the per-bucket device slabs are re-planned and any bucket
-  whose slab moved re-places its stack via
-  ``ReadoutServer.rebind_mesh`` — replicated serving state reshards
-  onto any slab size, the same property elastic train restarts rely
-  on. Resizing is a control-plane event (it MAY retrace); tenant
-  admission into an existing bucket never does.
+* **Grow/shrink** (launch.mesh.make_fleet_meshes +
+  ReadoutServer.rebind_mesh): buckets are created on demand
+  (``admit`` / ``prewarm``) and retired when empty (``shrink``); after
+  every resize the per-bucket device slabs are re-planned and any
+  bucket whose slab moved re-places its stack via
+  ``ReadoutServer.rebind_mesh``, each chip's rows on the device of the
+  new slab that serves it. Resizing is a control-plane event (it MAY
+  retrace); tenant admission into an existing bucket never does.
 
 Per-tenant accounting (``report()["tenants"]``) closes the identity::
 
@@ -402,8 +401,8 @@ class TenantFleet:
         """Retire every bucket with no resident tenants; returns how
         many were dropped. The SHRINK half of the fleet's elasticity:
         surviving buckets' device slabs are re-planned
-        (make_fleet_meshes) and re-placed via ``rebind_mesh`` /
-        ``reshard_replicated`` where they moved."""
+        (make_fleet_meshes) and re-placed via ``rebind_mesh`` where
+        they moved."""
         keep = [b for b in self._buckets
                 if any(s is not None for s in b.slots)]
         dropped = len(self._buckets) - len(keep)

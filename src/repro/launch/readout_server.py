@@ -564,6 +564,44 @@ class ChipStreamStats:
         return self.n_kept / self.n_in if self.n_in else 1.0
 
 
+class ShardLedger:
+    """What each device of the "chips" mesh was given by the kernel frames
+    path since the last reset: ``report()["shards"]``. The chip axis is
+    split into contiguous groups of ``modules_per_device`` modules (the
+    ``shard_map`` over the mesh), so device k holds the rows of modules
+    k*m .. k*m + m - 1. Counted once per dispatch, from the per-module
+    event counts, never per event."""
+
+    def __init__(self, devices: int, n_modules: int):
+        self.devices = devices
+        self.modules_per_device = n_modules // devices if devices else 0
+        self.reset()
+
+    def reset(self) -> None:
+        self.dispatches = 0
+        self.rows = [0] * self.devices
+        self.events = [0] * self.devices
+        self.bytes = [0] * self.devices
+
+    def add(self, counts: Sequence[int], width: int, row_bytes: int) -> None:
+        """One dispatch: ``counts`` real events per module, ``width`` rows
+        placed per module, ``row_bytes`` placed per row."""
+        m = self.modules_per_device
+        self.dispatches += 1
+        for k in range(self.devices):
+            self.rows[k] += m * width
+            self.events[k] += sum(counts[k * m:(k + 1) * m])
+            self.bytes[k] += m * width * row_bytes
+
+    def report(self) -> Dict[str, object]:
+        return {"devices": self.devices,
+                "modules_per_device": self.modules_per_device,
+                "dispatches": self.dispatches,
+                "rows_per_device": list(self.rows),
+                "events_per_device": list(self.events),
+                "bytes_per_device": list(self.bytes)}
+
+
 # (seq, chip, kind, payload, t_enqueue); payload is a features row for
 # kind="features", an (frame, y0) pair for kind="frames".
 _Event = Tuple[int, int, str, object, float]
@@ -681,16 +719,17 @@ class ReadoutServer:
             from repro.launch.mesh import make_readout_mesh
 
             self._lut_ops = lut_ops
+            # ONE readout mesh for both ingestion stages: the features
+            # path shards its scoring dispatch over the same "chips" axis
+            # as the fused frames frontend, and each device holds its own
+            # chips' rows of the stack.
+            self._mesh = make_readout_mesh(self.n_chips)
             self._stack = lut_ops.pack_fabrics(
                 [c.config for c in self.chips], band=config.band,
                 redundancy=config.redundancy, layout=self.layout,
                 geometry=(None if envelope is None else
                           dataclasses.replace(self.geometry, frontend=None)),
-            )
-            # ONE readout mesh for both ingestion stages: the features
-            # path shards its scoring dispatch over the same "chips" axis
-            # as the fused frames frontend.
-            self._mesh = make_readout_mesh(self.n_chips)
+            ).on_mesh(self._mesh)
             self._out_weight = lut_ops.decode_plan(
                 [c.config for c in self.chips], self._stack.n_outputs)
         else:
@@ -712,6 +751,9 @@ class ReadoutServer:
         # a batch is staged only while pipeline_depth or fewer are in
         # flight, so pipeline_depth + 1 arenas serve; one more is slack
         self._arenas = StagingArenas(config.pipeline_depth + 2)
+        # rows, real events and bytes each device received per dispatch
+        self._shards = ShardLedger(
+            0 if self._mesh is None else self._mesh.size, self.n_chips)
         self._stats = [
             ChipStreamStats(disagreements=[0] * self.n_replicas)
             for _ in self.chips
@@ -1291,11 +1333,13 @@ class ReadoutServer:
 
             frontend = self._get_frontend()
             word_sparse = self._word_sparse_active()
-            score = (frontend.score_frames_sparse if word_sparse
-                     else frontend.score_frames_voted)
             n_programs = frontend.compiled_programs()
             with self._stages.span("launch_fused", bid) as sp:
-                out = score(frames, y0, valid=valid)
+                with self._stages.span("place_frames", bid):
+                    placed = frontend.place(frames, y0, valid)
+                out = frontend.score_placed(placed, sparse=word_sparse)
+            self._shards.add(counts, placed.width, row_bytes=(
+                frames[0, 0].nbytes + y0.itemsize + valid.itemsize))
             if frontend.compiled_programs() > n_programs:
                 # this dispatch compiled (or loaded) a program
                 self._compiles += 1
@@ -1466,6 +1510,7 @@ class ReadoutServer:
         self._compiles = 0
         self._compile_s = 0.0
         self._arenas.reset_counts()
+        self._shards.reset()
         self._n_drained_window = 0
         self._n_batches_drained = 0
         self._deadline_met = 0
@@ -1710,32 +1755,26 @@ class ReadoutServer:
         mesh — the fleet grow/shrink port (launch/fleet.py).
 
         Pending work is flushed first (returned, like ``reconfigure``),
-        then the packed stack (and the fused frontend, if live) is
-        replicated onto the new mesh via
-        ``train.elastic.reshard_replicated`` — serving state is
-        replicated, so any slab size works, the same reason elastic
-        train restarts can reshard onto a shrunken mesh. Rebinding to a
-        mesh EQUAL to the current one (same devices, same axes) is free:
-        jit static-arg caching compares meshes by value, so nothing
+        then the packed stack and the fused frontend's encode plan (if
+        live) are placed on the new mesh, each chip's rows on the device
+        that serves it (``PackedFabricStack.on_mesh``), and the
+        ``shards`` ledger starts over for the new device count. Rebinding
+        to a mesh EQUAL to the current one (same devices, same axes) is
+        free: jit static-arg caching compares meshes by value, so nothing
         retraces. A genuinely different slab retraces once on the next
         dispatch — grow/shrink is a control-plane event, not the
         zero-retrace tenant-admission path. No-op on the host backend.
         """
         if self.config.backend != "kernel":
             return []
-        from repro.train.elastic import reshard_replicated
-
         done = self.flush()
-        rebound = self._mesh is None or mesh != self._mesh
-        if rebound:
-            self._stack = reshard_replicated(self._stack, mesh)
+        if mesh == self._mesh:
+            return done
         self._mesh = mesh
-        if self._frontend is not None and rebound:
-            self._frontend = dataclasses.replace(
-                self._frontend,
-                stack=self._stack,
-                mesh=mesh,
-            )
+        self._stack = self._stack.on_mesh(mesh)
+        self._shards = ShardLedger(mesh.size, self.n_chips)
+        if self._frontend is not None:
+            self._frontend = self._frontend.on_mesh(mesh, self._stack)
         return done
 
     # ----------------------------------------------------- fault injection
@@ -1993,27 +2032,32 @@ class ReadoutServer:
 
     # ------------------------------------------------------------ report
     def report(self) -> Dict[str, object]:
-        """Per-chip trigger/reduction accounting aggregated over the
-        stream, plus the per-stage host spans (seconds, calls and the
-        longest call per pipeline stage — for fused frames dispatches the
-        featurize/quantize/pack/vote/score stages are a single
-        ``launch_fused`` entry by design; the staged host path itemizes
-        them), the per-replica SEU disagreement counters, the measured
-        host-link bytes (sparse wire vs dense equivalent), and the scrub
-        accounting (steps/cycles/frames, CRC detections, healed config
-        bits, per-detection latency in dispatches). The deadline-aware
-        additions: per-chip and total latency histograms (p50/p99/p99.9
-        + CDF), the last drained batch's stage trace and the phases of
-        the newest batches (``latency.phases``), the fused-step dispatches
-        that compiled while serving (``compiles``), the kernel frames
-        path's staging arenas (``staging``: dispatches that ``reused`` an
-        arena or allocated a ``fresh`` buffer since the reset, the
-        ``arenas`` alive and their ``resident_bytes``), the met/missed/
-        shed deadline ledger, the adaptive coalescer's effective knobs,
-        and the degrade ladder's level + timestamped transitions. With a
-        network front door attached (net/ingress.py), ``"net"`` carries
-        its per-client drop/reorder/resync accounting snapshot;
-        otherwise ``{"attached": False}``."""
+        """Per-chip trigger/reduction accounting aggregated over the stream,
+        plus the per-stage host spans (seconds, calls and the longest call per
+        pipeline stage — for fused frames dispatches the
+        featurize/quantize/pack/vote/score stages are a single ``launch_fused``
+        entry by design, with the sharded placement of the batch timed inside
+        it as ``place_frames``; the staged host path itemizes them), the
+        per-replica SEU disagreement counters, the measured host-link bytes
+        (sparse wire vs dense equivalent), and the scrub accounting
+        (steps/cycles/frames, CRC detections, healed config bits, per-detection
+        latency in dispatches). The deadline-aware additions: per-chip and
+        total latency histograms (p50/p99/p99.9 + CDF), the last drained
+        batch's stage trace and the phases of the newest batches
+        (``latency.phases``), the fused-step dispatches that compiled while
+        serving (``compiles``), the kernel frames path's staging arenas
+        (``staging``: dispatches that ``reused`` an arena or allocated a
+        ``fresh`` buffer since the reset, the ``arenas`` alive and their
+        ``resident_bytes``), what each device of the "chips" mesh was given by
+        those dispatches (``shards``: ``devices``, ``modules_per_device``, and
+        since the reset or a mesh rebind the ``dispatches`` and per device the
+        event ``rows_per_device`` placed, the real ``events_per_device`` and
+        the ``bytes_per_device`` of frames, y0 and valid), the met/missed/shed
+        deadline ledger, the adaptive coalescer's effective knobs, and the
+        degrade ladder's level + timestamped transitions. With a network front
+        door attached (net/ingress.py), ``"net"`` carries its per-client
+        drop/reorder/resync accounting snapshot; otherwise ``{"attached":
+        False}``."""
         cfg = self.config
         per_chip = []
         for i, st in enumerate(self._stats):
@@ -2099,6 +2143,7 @@ class ReadoutServer:
             "compiles": {"dispatches": self._compiles,
                          "seconds": self._compile_s},
             "staging": self._arenas.report(),
+            "shards": self._shards.report(),
             "deadline": {
                 "deadline_us": cfg.deadline_us,
                 "policy": cfg.overload_policy,

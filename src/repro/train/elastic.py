@@ -55,24 +55,3 @@ def gather_to_host(tree: PyTree) -> PyTree:
     import numpy as np
 
     return jax.tree.map(lambda x: np.asarray(x), tree)
-
-
-def reshard_replicated(tree: PyTree, mesh: Mesh) -> PyTree:
-    """Replicate a pytree's arrays onto every device of a (new) mesh.
-
-    The serving-side analogue of ``reshard_params``: the multi-tenant
-    fleet (launch/fleet.py) re-plans its per-bucket device slabs on every
-    grow/shrink (launch.mesh.make_fleet_meshes), and a bucket whose slab
-    moved re-places its packed kernel stack here — the stack is
-    replicated (the chip axis is split by shard_map at dispatch, not by
-    layout), so the placement spec is pure replication and any slab size
-    works, exactly like checkpointed train state resharding onto a
-    shrunken mesh. Static pytree fields and ``None`` leaves pass through
-    untouched.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    sharding = NamedSharding(mesh, PartitionSpec())
-    return jax.tree.map(
-        lambda x: jax.device_put(x, sharding) if x is not None else None,
-        tree)
