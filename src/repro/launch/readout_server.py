@@ -121,11 +121,13 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import logging
 import math
 import time
 from typing import (
-    Callable, Deque, Dict, Iterable, List, Optional, Sequence, Tuple,
+    Callable, Deque, Dict, Iterable, List, NamedTuple, Optional, Sequence,
+    Tuple,
 )
 
 import numpy as np
@@ -538,8 +540,11 @@ class ServerConfig:
         return None if self.deadline_us is None else self.deadline_us * 1e-6
 
 
-@dataclasses.dataclass(frozen=True)
-class ScoredEvent:
+class ScoredEvent(NamedTuple):
+    """One event's answer: immutable and equal by value. A retired
+    batch's answers are built together from its columns
+    (``ReadoutServer._answers``), not one constructor call per event."""
+
     seq: int          # submission order (global, monotone)
     chip: int
     score_raw: int    # integer-domain fabric score (voted under TMR)
@@ -771,7 +776,10 @@ class ReadoutServer:
         self._t_start: Optional[float] = None
         self._t_last: Optional[float] = None
         self._n_drained_window = 0
-        self._n_scored = 0
+        # answers retired since the last reset, and the batches whose
+        # answers needed a sort into seq order (report()["drain"])
+        self._drain_answers = 0
+        self._drain_reordered = 0
         # measured host-link accounting: bytes actually materialized on
         # the wire (sparse packs when a batch drains sparse, dense rows
         # otherwise — the sparse_egress rung can mix both on one server)
@@ -1444,7 +1452,6 @@ class ReadoutServer:
         if not self._inflight:
             return []
         kind, pending, per_chip_seq, counts, meta = self._inflight.popleft()
-        results: List[ScoredEvent] = []
         n_events = int(sum(counts))
         with self._stages.span("drain_wait", meta["batch"]) as sp:
             if kind == "sparse":
@@ -1455,49 +1462,66 @@ class ReadoutServer:
                 self._link_bytes_wire += (
                     SPARSE_HEADER_BYTES + SPARSE_BYTES_PER_EVENT * n_kept)
                 self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
-                kept_per_chip = np.bincount(
-                    idx_h // max(B, 1), minlength=self.n_chips)
+                chip, pos = np.divmod(idx_h, max(B, 1))
+                kept_per_chip = np.bincount(chip, minlength=self.n_chips)
                 for i, st in enumerate(self._stats):
                     st.n_in += counts[i]
                     st.n_kept += int(kept_per_chip[i])
-                for k, v in zip(idx_h, vals_h):
-                    chip, pos = int(k) // B, int(k) % B
-                    results.append(ScoredEvent(
-                        seq=per_chip_seq[chip][pos], chip=chip,
-                        score_raw=int(v), keep=True))
-                self._fold_disagreements(dis)
+                # every admitted event's seq, chip-major, and where each
+                # chip's events start in it
+                seq = np.concatenate(
+                    [np.asarray(s, np.int64) for s in per_chip_seq])
+                first = np.cumsum(counts) - counts
+                results = self._answers(seq[first[chip] + pos], chip, vals_h,
+                                        np.ones(n_kept, bool))
             else:  # "scored"
                 score, keep, dis = pending
                 score = np.asarray(score)                   # blocks here
-                keep = np.asarray(keep)
+                keep = np.asarray(keep, bool)
                 self._link_bytes_wire += DENSE_BYTES_PER_EVENT * n_events
                 self._link_bytes_dense += DENSE_BYTES_PER_EVENT * n_events
+                cols: List[Tuple[np.ndarray, ...]] = []
                 for i in range(self.n_chips):
                     n = counts[i]
-                    if not n:
-                        continue
-                    self._fold_chip(results, i, per_chip_seq[i],
-                                    score[i, :n].astype(np.int64),
-                                    keep[i, :n])
-                self._fold_disagreements(dis)
+                    if n:
+                        self._fold_chip(cols, i, per_chip_seq[i],
+                                        score[i, :n], keep[i, :n])
+                results = self._answers(*map(np.concatenate, zip(*cols)))
+            self._fold_disagreements(dis)
         # the results have materialized, so the step that read the staged
         # frames is done: their arena may take the next batch
         self._arenas.give(meta.get("arena"))
-        self._n_scored += len(results)
         self._n_drained_window += n_events
         meta["trace"]["t_collect"] = sp.t0
         t_done = sp.t1
         self._t_last = t_done
         self._observe_batch(meta, t_done)
-        results.sort(key=lambda r: r.seq)
         return results
+
+    def _answers(self, seq: np.ndarray, chip: np.ndarray, score: np.ndarray,
+                 keep: np.ndarray) -> List[ScoredEvent]:
+        """One retired batch's answers in seq order, built from its
+        columns (one entry per answer) in one pass. The columns come
+        chip-major; they are already in seq order unless blocks of
+        different chips interleaved in the queue, and only then take one
+        stable argsort (counted as ``report()["drain"]["reordered"]``)."""
+        if not (seq[1:] > seq[:-1]).all():
+            order = np.argsort(seq, kind="stable")
+            seq, chip, score, keep = (
+                seq[order], chip[order], score[order], keep[order])
+            self._drain_reordered += 1
+        self._drain_answers += len(seq)
+        # tuple.__new__ is what ScoredEvent._make calls, without its
+        # per-answer Python frame and length check (zip gives 4 fields)
+        return list(map(tuple.__new__, itertools.repeat(ScoredEvent), zip(
+            seq.tolist(), chip.tolist(), score.tolist(), keep.tolist())))
 
     # ------------------------------------------- latency / deadline loop
     def reset_latency_metrics(self) -> None:
         """Zero the latency/deadline ledger (histograms, the batch phase
         ring, the stages' longest calls, the compile counter, the staging
-        arenas' reused/fresh counts, met/missed/shed counters, the EWMA
-        seed and the throughput window) without
+        arenas' reused/fresh counts, the drain counters, met/missed/shed
+        counters, the EWMA seed and the throughput window) without
         touching trigger accounting, scrub state or the ladder level —
         for measuring a warmed-up server: jit compilation of the first
         dispatch otherwise dominates every percentile of a short run."""
@@ -1513,6 +1537,8 @@ class ReadoutServer:
         self._shards.reset()
         self._n_drained_window = 0
         self._n_batches_drained = 0
+        self._drain_answers = 0
+        self._drain_reordered = 0
         self._deadline_met = 0
         self._deadline_missed = 0
         self._service_ewma_s = 0.0
@@ -1666,15 +1692,14 @@ class ReadoutServer:
                 self._scrub_healed_bits += self._heal_frame(
                     slot, replica, image)
 
-    def _fold_chip(self, results, i, seqs, scores, keep) -> None:
+    def _fold_chip(self, cols, i, seqs, scores, keep) -> None:
+        """Count chip i's share of a dense batch and add its answers to
+        the batch's columns as one (seq, chip, score, keep) group."""
         st = self._stats[i]
         st.n_in += len(seqs)
-        st.n_kept += int(np.asarray(keep).sum())
-        for j, seq in enumerate(seqs):
-            results.append(
-                ScoredEvent(seq=seq, chip=i, score_raw=int(scores[j]),
-                            keep=bool(keep[j]))
-            )
+        st.n_kept += int(np.count_nonzero(keep))
+        cols.append((np.asarray(seqs, np.int64), np.full(len(seqs), i),
+                     scores, keep))
 
     def _fold_disagreements(self, dis) -> None:
         dis = np.asarray(dis)                           # (C, R)
@@ -2052,7 +2077,9 @@ class ReadoutServer:
         those dispatches (``shards``: ``devices``, ``modules_per_device``, and
         since the reset or a mesh rebind the ``dispatches`` and per device the
         event ``rows_per_device`` placed, the real ``events_per_device`` and
-        the ``bytes_per_device`` of frames, y0 and valid), the met/missed/shed
+        the ``bytes_per_device`` of frames, y0 and valid), the retired batches
+        (``drain``: ``batches``, ``answers`` returned and the batches
+        ``reordered`` into seq order by a sort), the met/missed/shed
         deadline ledger, the adaptive coalescer's effective knobs, and the
         degrade ladder's level + timestamped transitions. With a network front
         door attached (net/ingress.py), ``"net"`` carries its per-client
@@ -2144,6 +2171,9 @@ class ReadoutServer:
                          "seconds": self._compile_s},
             "staging": self._arenas.report(),
             "shards": self._shards.report(),
+            "drain": {"batches": self._n_batches_drained,
+                      "answers": self._drain_answers,
+                      "reordered": self._drain_reordered},
             "deadline": {
                 "deadline_us": cfg.deadline_us,
                 "policy": cfg.overload_policy,
