@@ -13,9 +13,9 @@ every one bit-exact against the host oracle. Prints per-sensor achieved
 rate + end-to-end latency percentiles and the door's per-client
 accounting (``report()["net"]``).
 
-``--rate 0`` floods unpaced (the loopback-throughput configuration);
-see ``benchmarks/bench_net.py`` for the calibrated comparison against
-the in-process rate.
+``--rate 0`` floods unpaced (the loopback-throughput configuration).
+Rates here are host-clock rates of this machine's loopback, not device
+speeds; the front door has no benchmark cell yet.
 """
 import argparse
 import asyncio

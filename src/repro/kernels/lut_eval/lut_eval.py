@@ -36,8 +36,8 @@ scoped limit is sized from the blocks (``_compiler_params``).
 
 The selection matmul does ~B*N*4M flops per level — far more "arithmetic"
 than the fabric's actual logic, but it is dense MXU work at 197 TFLOP/s
-instead of serialized gathers; benchmarks/bench_fabric.py reports the
-events/s this buys.
+instead of serialized gathers. What it buys on the chip against the
+bit-sliced layout is not measured.
 
 Banded variant (``lut_eval_pallas_banded_stacked``): levelized netlists
 have bounded fan-in reach — a level-l LUT reads only primary inputs plus a

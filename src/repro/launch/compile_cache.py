@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache for the command-line entry points.
 
 Only entry points (``chip_smoke.py``, ``examples/serve_readout.py``,
-``benchmarks/run.py``) call ``enable_compile_cache``; importing a library
-module never changes JAX's configuration, and tests never cache.
+``benchmarks/readout/run.py``) call ``enable_compile_cache``; importing a
+library module never changes JAX's configuration, and tests never cache.
 """
 from __future__ import annotations
 

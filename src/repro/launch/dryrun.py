@@ -20,8 +20,7 @@ For each cell this driver:
   4. prints ``compiled.memory_analysis()`` (fits-in-HBM proof) and
      ``cost_analysis()`` (FLOPs/bytes for §Roofline), parses collective
      wire bytes from the compiled HLO,
-  5. writes reports/dryrun/<mesh>/<arch>__<shape>.json for
-     benchmarks/roofline.py.
+  5. writes reports/dryrun/<mesh>/<arch>__<shape>.json.
 
 Failures here (sharding mismatch, OOM at compile, unsupported collective)
 are bugs in the system — the run aborts non-zero unless --keep-going.

@@ -62,9 +62,9 @@ Key properties:
     starve a frame. Kernel-backend readbacks are issued as ASYNC
     device->host copies and verified one scrub step later, so the scrub
     task interleaves behind the in-flight dispatches instead of stalling
-    the triple-buffered pipeline (a synchronous readback costs ~25%
-    events/s; the async split keeps the measured overhead under the 5%
-    budget — BENCH_fabric.json ``fabric.scrub_overhead``). Works without
+    the triple-buffered pipeline (a synchronous readback cost ~25%
+    events/s and the async split under 5%, both measured on a CPU with
+    the matmul layouts; neither is measured on the chip). Works without
     redundancy too: CRC-only detection heals an unprotected chip
     (outputs may be wrong until the heal — exactly the window scrubbing
     bounds).
@@ -161,10 +161,11 @@ from repro.parallel.compression import (
 )
 
 # The documented default scrub budget: one readback->verify step every
-# this many scoring dispatches. Chosen so the benchmark's sustained-stream
-# throughput cost stays under 5% (benchmarks/bench_fabric.py
-# fabric.scrub_overhead); deployments trade detection latency against
-# overhead by setting ServerConfig(scrub_interval=...) directly.
+# this many scoring dispatches. Chosen when a sustained stream's
+# throughput cost at this interval measured under 5% on a CPU, with the
+# matmul layouts; the cost on the chip is not measured. Deployments trade
+# detection latency against overhead by setting
+# ServerConfig(scrub_interval=...) directly.
 DEFAULT_SCRUB_INTERVAL = 4
 
 # The degrade ladder's known rungs, in the order the default ladder steps
@@ -192,7 +193,7 @@ _LOG = logging.getLogger("repro.launch.readout_server")
 # decade from 1 us to 100 s, plus an underflow and an overflow slot. A
 # FIXED grid (rather than per-stream quantile sketches) keeps the state
 # O(1) no matter how many events stream through, makes histograms
-# mergeable across chips and runs, and gives the bench JSON a stable,
+# mergeable across chips and runs, and gives ``report()`` a stable,
 # machine-comparable CDF axis.
 _HIST_BUCKETS_PER_DECADE = 8
 _HIST_DECADES = 8
@@ -266,7 +267,7 @@ class LatencyHistogram:
 
     def cdf(self) -> List[List[float]]:
         """[[upper edge us, cumulative fraction], ...] over the non-empty
-        buckets — the machine-readable CDF exported to the bench JSON.
+        buckets — the machine-readable CDF ``report()`` exports.
         Underflow folds into the first emitted point; the final point is
         the observed max at fraction 1.0."""
         total = int(self.counts.sum())

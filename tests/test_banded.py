@@ -62,6 +62,24 @@ def _long_edge_netlist(n_inputs: int, chain: int):
     return b.build()
 
 
+def assert_sparse_ships_kept(stack, bits, w, thr, golden, tag=""):
+    """One scored dispatch through the word-domain sparse egress ships
+    exactly the events at or under the cut, with their golden scores,
+    and no replica disagrees."""
+    from repro.launch.mesh import make_readout_mesh
+    from repro.parallel.compression import sparse_trigger_unpack
+
+    kept = golden <= thr[0]
+    count, idx, vals, dis = lut_ops.fabric_eval_multi_scored_sparse(
+        stack, bits, w, thr, mesh=make_readout_mesh(1))
+    assert int(np.asarray(count)) == int(kept.sum()), tag
+    s2, k2 = sparse_trigger_unpack(np.asarray(idx), np.asarray(vals),
+                                   (1, len(golden)))
+    np.testing.assert_array_equal(k2[0], kept, err_msg=tag)
+    np.testing.assert_array_equal(s2[0], golden * kept, err_msg=tag)
+    assert not np.asarray(dis).any(), tag
+
+
 @pytest.fixture(scope="module")
 def ensemble_parts():
     d = generate(SmartPixelConfig(n_events=10_000, seed=21))
@@ -239,6 +257,37 @@ def test_tree_reduction_cuts_depth_and_reach(ensemble_parts):
     X_raw = ens.quantize_features(X[:600])
     assert verify_against_golden(s_ripple, ens, X_raw)["accuracy"] == 1.0
     assert verify_against_golden(s_tree, ens, X_raw)["accuracy"] == 1.0
+
+
+@pytest.mark.parametrize("band,layout", [(False, "matmul"),
+                                         (None, "matmul"),
+                                         (None, "bitsliced")])
+@pytest.mark.parametrize("adder", ["ripple", "tree"])
+def test_deep_ensemble_every_layout_matches_golden(ensemble_parts, adder,
+                                                   band, layout):
+    """The 4-tree ensemble on efpga_28nm_xl equals the golden quantized
+    model under both summation structures through every evaluator: dense
+    and auto-banded matmul, and bit-sliced. The bit-sliced word-domain
+    sparse egress ships exactly the events under the cut at 90, 50 and
+    10 % accept."""
+    ens, X = ensemble_parts
+    synth = synth_ensemble(ens, adder=adder)
+    cfg = place_and_route(synth.netlist, FABRICS["efpga_28nm_xl"])
+    X_raw = ens.quantize_features(X[:100])
+    golden = ens.decision_function_raw(X_raw)
+    bits = synth.encode_inputs(X_raw)
+    got = np.asarray(lut_ops.fabric_eval(
+        lut_ops.pack_fabric(cfg, band=band, layout=layout), bits))
+    np.testing.assert_array_equal(synth.decode_outputs(got), golden)
+    if layout != "bitsliced":
+        return
+
+    stack = lut_ops.pack_fabrics([cfg], layout="bitsliced")
+    w = lut_ops.decode_plan([cfg], stack.n_outputs)
+    for pct in (90, 50, 10):
+        thr = np.array([int(np.percentile(golden, pct))], np.int32)
+        assert_sparse_ships_kept(stack, bits[None], w, thr, golden,
+                                 f"p{pct}")
 
 
 def test_synth_rejects_unknown_adder(ensemble_parts):

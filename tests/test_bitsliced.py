@@ -43,7 +43,9 @@ from repro.kernels.lut_eval import bitsliced, ops as lut_ops
 from repro.launch.mesh import make_readout_mesh
 from repro.launch.readout_server import ReadoutServer, ServerConfig
 from tests._propshim import given, settings, strategies as st
-from tests.test_banded import _layered_netlist, _long_edge_netlist
+from tests.test_banded import (
+    _layered_netlist, _long_edge_netlist, assert_sparse_ships_kept,
+)
 from tests.test_kernels import _random_netlist
 
 import repro.core.tmr  # noqa: F401  (registers efpga_28nm_xl)
@@ -138,28 +140,34 @@ def test_padding_lanes_never_leak(seed, n_events):
     np.testing.assert_array_equal(got_big[:n_events], want)
 
 
-def test_padding_lanes_never_leak_into_scores(farm):
+@pytest.mark.parametrize("red", ["none", "tmr"])
+@pytest.mark.parametrize("band", [False, None])
+@pytest.mark.parametrize("layout", ["matmul", "bitsliced"])
+def test_padding_lanes_never_leak_into_scores(farm, layout, band, red):
     """The scored dispatch (the server's launch path) on a batch that
-    straddles a word boundary: bit-sliced scores == matmul scores ==
-    golden, event for event."""
+    straddles a word boundary, through each packing the server can be
+    configured with (layout x band dense/auto x TMR off/on): scores ==
+    golden and keep == the cut, event for event, with no replica
+    disagreement; the bit-sliced packings' word-domain sparse egress
+    ships exactly the kept events."""
     chips, X = farm
     chip = chips[0]
     assert len(X) % 32 != 0
     bits = chip.encode_features(X)[None]
     thr = np.array([chip.score_threshold_raw], np.int32)
-    mesh = make_readout_mesh(1)
     golden = _golden(chip, X)
-    for layout in ("matmul", "bitsliced"):
-        stack = lut_ops.pack_fabrics([chip.config], redundancy="tmr",
-                                     layout=layout)
-        w = lut_ops.decode_plan([chip.config], stack.n_outputs)
-        score, keep, dis = lut_ops.fabric_eval_multi_scored(
-            stack, bits, w, thr, mesh=mesh)
-        np.testing.assert_array_equal(np.asarray(score)[0], golden,
-                                      err_msg=layout)
-        np.testing.assert_array_equal(
-            np.asarray(keep)[0], golden <= chip.score_threshold_raw)
-        assert not np.asarray(dis).any(), layout
+    stack = lut_ops.pack_fabrics([chip.config], band=band, redundancy=red,
+                                 layout=layout)
+    w = lut_ops.decode_plan([chip.config], stack.n_outputs)
+    score, keep, dis = lut_ops.fabric_eval_multi_scored(
+        stack, bits, w, thr, mesh=make_readout_mesh(1))
+    np.testing.assert_array_equal(np.asarray(score)[0], golden)
+    np.testing.assert_array_equal(np.asarray(keep)[0],
+                                  golden <= chip.score_threshold_raw)
+    assert not np.asarray(dis).any()
+    if layout == "bitsliced":
+        assert_sparse_ships_kept(stack, bits, w, thr, golden,
+                                 f"band={band} red={red}")
 
 
 # --------------------------------------------- the conformance matrix
@@ -210,6 +218,37 @@ def test_bitsliced_stack_tmr_matches_plain_and_multisim(farm):
             err_msg=f"red={red} vs matmul")
 
 
+@pytest.fixture(scope="module")
+def quad():
+    """Four paper-sized chips of differing depth and leaf count, and a
+    feature batch off the 32-event word boundary."""
+    d = generate(SmartPixelConfig(n_events=10_000, seed=2024))
+    tr, te = train_test_split(d)
+    chips = [
+        ReadoutChip.build(GradientBoostedClassifier(
+            n_estimators=1, max_depth=5 - (i % 2),
+            max_leaf_nodes=10 - (i % 3), min_samples_leaf=500,
+        ).fit(tr["features"], tr["label"]))
+        for i in range(4)
+    ]
+    return chips, te["features"][:100]
+
+
+@pytest.mark.parametrize("n_chips", [1, 2, 4])
+def test_multichip_stack_one_dispatch_matches_multisim(quad, n_chips):
+    """1, 2 and 4 heterogeneous chips stacked on the bit-sliced layout and
+    evaluated in ONE fabric_eval_multi dispatch equal the per-chip host
+    oracle bit for bit."""
+    chips, X = quad
+    configs = [c.config for c in chips[:n_chips]]
+    stack = lut_ops.pack_fabrics(configs, layout="bitsliced")
+    bits = lut_ops.stack_input_bits(
+        stack, [c.encode_features(X) for c in chips[:n_chips]])
+    got = np.asarray(lut_ops.fabric_eval_multi(stack, bits))
+    assert got.shape[:2] == (n_chips, len(X))
+    np.testing.assert_array_equal(got, MultiFabricSim(configs).run(bits))
+
+
 def test_server_matrix_bitsliced_matches_matmul(farm):
     """The served results (scores, keep decisions, sequence) through the
     kernel server are identical for layout='bitsliced' and 'matmul'
@@ -251,6 +290,47 @@ def test_server_frames_bitsliced_matches_matmul(farm):
         out[layout] = [(r.seq, r.score_raw, r.keep) for r in res]
     assert out["bitsliced"] == out["matmul"]
     assert len(out["bitsliced"]) == len(frames)
+
+
+@pytest.mark.parametrize("red", ["none", "tmr"])
+def test_server_frames_sparse_two_chips_matches_golden(farm, red):
+    """The fused frames path with sparse egress on two chips (kernel
+    backend, bit-sliced, the sparse body plus its cross-chip compaction):
+    the served answers are exactly the golden model's kept events, as
+    (seq, chip, score), and the same as the kept answers of the dense
+    twin."""
+    import copy
+
+    from repro.kernels.yprofile import ops as yp_ops
+
+    d = generate(SmartPixelConfig(n_events=90, seed=9), return_frames=True)
+    frames, y0 = d["frames"], d["features"][:, 13]
+    feats = np.asarray(yp_ops.yprofile(frames, y0, batch_tile=128))
+    parts = (slice(0, 45), slice(45, 90))   # chip i serves seqs 45i..
+    chips, want = [], []
+    for i, (c, sl) in enumerate(zip(farm[0], parts)):
+        g = _golden(c, feats[sl])
+        c = copy.copy(c)        # a median cut: sparse drops about half
+        c.score_threshold_raw = int(np.median(g))
+        chips.append(c)
+        want += [(sl.start + j, i, int(s)) for j, s in enumerate(g)
+                 if s <= c.score_threshold_raw]
+    assert 0 < len(want) < len(frames)
+    out = {}
+    for sparse in (False, True):
+        srv = ReadoutServer(chips, ServerConfig(
+            max_batch=64, max_latency_s=1e9, backend="kernel",
+            layout="bitsliced", redundancy=red, sparse=sparse))
+        for i, sl in enumerate(parts):
+            srv.submit_frames(i, frames[sl], y0[sl])
+        res = sorted(srv.flush(), key=lambda r: r.seq)
+        assert srv.report()["seu_disagreement_total"] == 0
+        out[sparse] = [(r.seq, r.chip, r.score_raw) for r in res
+                       if r.keep]
+        if not sparse:
+            assert len(res) == len(frames)
+    assert out[True] == want
+    assert out[False] == want
 
 
 # ------------------------------------------------ hot-swap / no-retrace
@@ -318,8 +398,6 @@ def test_banded_bitsliced_conformance_matrix():
     same gather kernel, stricter admission) serves scores bit-exact vs
     MultiFabricSim and the banded BitslicedSim host oracle, and the
     word-domain sparse egress ships exactly the kept subset."""
-    from repro.parallel.compression import sparse_trigger_unpack
-
     mesh = make_readout_mesh(1)
     fabric_names = sorted({s.name for s in FABRICS.values()})
     assert {"efpga_130nm", "efpga_28nm", "efpga_28nm_xl"} <= set(fabric_names)
@@ -353,16 +431,7 @@ def test_banded_bitsliced_conformance_matrix():
                     np.asarray(keep)[0], kept, err_msg=tag)
                 assert not np.asarray(dis).any(), tag
                 # sparse cell: word-domain egress == the kept subset
-                count, idx, vals, dis2 = (
-                    lut_ops.fabric_eval_multi_scored_sparse(
-                        stack, bits, w, thr, mesh=mesh))
-                assert int(np.asarray(count)) == int(kept.sum()), tag
-                s2, k2 = sparse_trigger_unpack(
-                    np.asarray(idx), np.asarray(vals), (1, B))
-                np.testing.assert_array_equal(k2[0], kept, err_msg=tag)
-                np.testing.assert_array_equal(
-                    s2[0], golden * kept, err_msg=tag)
-                assert not np.asarray(dis2).any(), tag
+                assert_sparse_ships_kept(stack, bits, w, thr, golden, tag)
 
 
 @given(seed=st.integers(0, 500))

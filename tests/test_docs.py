@@ -1,13 +1,15 @@
 """Docs-coverage gate (fast tier): the operator docs cannot silently
 drift from the code. Every ``report()`` top-level key (server AND
 fleet, plus the per-tenant ledger) must appear in
-docs/architecture.md, and every regression-gate key / required bench
-prefix must appear in docs/benchmarks.md — keys are derived LIVE from
-the running code, so adding a counter without documenting it fails CI.
+docs/architecture.md — keys are derived LIVE from the running code, so
+adding a counter without documenting it fails CI — and every cell and
+metric that BENCHMARK.json declares must appear in docs/benchmarks.md.
 """
+import json
 from pathlib import Path
 
-DOCS = Path(__file__).resolve().parent.parent / "docs"
+ROOT = Path(__file__).resolve().parent.parent
+DOCS = ROOT / "docs"
 
 
 def test_architecture_documents_every_report_key():
@@ -33,14 +35,12 @@ def test_architecture_documents_every_report_key():
         f"report() keys missing from docs/architecture.md: {missing}")
 
 
-def test_benchmarks_doc_covers_every_gate_key_and_prefix():
-    from benchmarks import check_regression as cr
-
+def test_benchmarks_doc_names_every_cell_and_metric():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    names = ([w["name"] for w in bench["workloads"]]
+             + [m["name"] for m in bench["end_to_end"]]
+             + [m["name"] for m in bench["per_layer"]])
     text = (DOCS / "benchmarks.md").read_text()
-    missing = [k for (k, name, field, *_r) in cr.TRACKED
-               if f"`{k}`" not in text]
-    missing += [name for (_k, name, field, *_r) in cr.TRACKED
-                if f"`{name}`" not in text]
-    missing += [p for p in cr.REQUIRED_PREFIXES if f"`{p}`" not in text]
+    missing = [n for n in names if f"`{n}`" not in text]
     assert not missing, (
-        f"gate keys/prefixes missing from docs/benchmarks.md: {missing}")
+        f"BENCHMARK.json names missing from docs/benchmarks.md: {missing}")

@@ -342,23 +342,6 @@ def test_readout_mesh_single_device():
         make_readout_mesh(0)
 
 
-def test_bench_json_has_frames_fused_scenario():
-    """The committed benchmark record must carry the fused-frontend
-    scenario, including a measured speedup row vs host-featurize."""
-    import json
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), "..", "BENCH_fabric.json")
-    with open(path) as f:
-        doc = json.load(f)
-    names = {r["name"] for r in doc["records"]}
-    assert any(n.startswith("fabric.frames_fused_") for n in names), names
-    assert any(n.startswith("fabric.frames_host_featurize_") for n in names)
-    speedups = [r for r in doc["records"]
-                if r["name"] == "fabric.frames_fused_speedup"]
-    assert speedups and "speedup" in speedups[0]
-
-
 # ------------------------------------------------------------- slow tier
 @pytest.mark.slow
 def test_fused_wide_sweep_all_fabrics_banded_dense(farm):

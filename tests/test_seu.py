@@ -17,8 +17,7 @@ monitor) record the upset. Structure:
       ``server.inject_seu`` — flips are healed by re-flipping the same
       bit, so one server serves the whole subsample with no repacking;
     * the double-fault negative controls, the sparse-readout semantics,
-      hot-swap/no-retrace under TMR, config validation, and the
-      committed-benchmark keys.
+      hot-swap/no-retrace under TMR, and config validation.
   slow tier (nightly)
     * the FULL sweep — every LUT x every truth-table bit of one replica —
       per registered fabric on the host-oracle server, plus an every-LUT
@@ -481,22 +480,6 @@ def test_tmr_swap_replica_rejects_mismatched_io(farm):
             stack.swap_replica(0, 1, b.config)
     with pytest.raises(ValueError, match="replica"):
         stack.swap_replica(0, 5, a.config)
-
-
-# ------------------------------------------------------ committed bench
-def test_bench_json_has_tmr_sparse_scenario():
-    """The committed benchmark record must carry the TMR + sparse-link
-    scenario, including measured bytes-on-wire (the CI fast tier asserts
-    the same keys on the freshly-generated smoke JSON)."""
-    path = os.path.join(os.path.dirname(__file__), "..", "BENCH_fabric.json")
-    with open(path) as f:
-        doc = json.load(f)
-    names = {r["name"] for r in doc["records"]}
-    assert any(n.startswith("fabric.tmr_sparse_") for n in names), names
-    rows = [r for r in doc["records"]
-            if r["name"] == "fabric.tmr_sparse_link_bytes"]
-    assert rows and "link_bytes_sparse" in rows[0] and \
-        "wire_reduction" in rows[0]
 
 
 # ------------------------------------------------------------- slow tier

@@ -25,8 +25,8 @@ C, B, TILE = 4, 2048, 128
 # the paper BDT stack (ops.pack_fabrics of the four tenant chips)
 L, M, IN_SEG, N_IN, N_OUT, BAND_K = 13, 128, 256, 224, 28, 7
 N_PAD = IN_SEG + L * M
-# deep ensembles on efpga_28nm_xl (benchmarks/bench_fabric.py
-# deep_ensemble4): (levels, band rows or None for dense), m_pad 256
+# deep ensembles on efpga_28nm_xl (4 trees of depth 3, ripple or tree
+# adders): (levels, band rows or None for dense), m_pad 256
 DEEP = {"dense_ripple": (25, None), "banded_ripple": (25, 128 + 18 * 256),
         "dense_tree": (19, None), "banded_tree": (19, 128 + 6 * 256)}
 
